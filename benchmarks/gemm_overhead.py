@@ -54,8 +54,10 @@ def _abft_encode(a, b):
 
 
 def _abft_pallas(a, b_packed):
-    # the fused kernel, interpret mode (already jitted with static args)
-    return abft_qgemm_pallas(a, b_packed, interpret=True)
+    # the fused kernel (already jitted with static args); interpret mode
+    # off the TPU only
+    return abft_qgemm_pallas(
+        a, b_packed, interpret=jax.devices()[0].platform != "tpu")
 
 
 @jax.jit

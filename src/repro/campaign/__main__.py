@@ -135,9 +135,11 @@ def main(argv=None) -> int:
                                       pallas_specs, paper_specs,
                                       quick_specs, thresholds_specs,
                                       training_specs, victims_specs)
+    from repro.compile_cache import enable_compile_cache
 
     # warns and falls back when the flag landed after jax initialized
     resolve_device_count(args.device_count or None)
+    enable_compile_cache()
 
     obs = None
     if args.obs_dir or args.monitor:

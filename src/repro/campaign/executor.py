@@ -46,13 +46,20 @@ class CellResult:
     seconds: float
 
 
-def resolve_device_count(requested: Optional[int] = None) -> int:
+def resolve_device_count(requested: Optional[int] = None, *,
+                         strict: bool = False) -> int:
     """Validate a requested host-device count against what jax actually
     has.  ``--device-count`` only works when XLA_FLAGS lands before jax
     initializes; when it didn't (library use, jax already imported), the
     old behavior was to trust the caller and die in a pmap reshape —
-    now we warn and fall back to ``jax.local_device_count()``."""
+    now we warn and fall back to ``jax.local_device_count()``.
+
+    ``strict=True`` (a run that needs real chips, not a forced host
+    platform) makes too few devices an error instead."""
     avail = jax.local_device_count()
+    if strict and requested and requested > avail:
+        raise RuntimeError(f"requested {requested} devices but only "
+                           f"{avail} exist")
     if requested and requested > avail:
         warnings.warn(
             f"requested {requested} host devices but only {avail} exist "

@@ -47,14 +47,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import LANE, MOD
 
-# jax < 0.5 names this TPUCompilerParams; newer releases dropped the prefix.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(a_ref, bp_ref, *refs, n_tiles: int, k_tiles: int, m_tiles: int,
             mod: int, zero_point: int, valid_m: int, with_colcheck: bool,
-            bn: int):
+            bn: int, col_digits: int):
     # refs = outputs (c, err[, col]) then scratches (acc, rowsum[, bcol]
     # [, colacc]) — the optional ones exist only when their static flag is
     # set, so unpack by the same flags.
@@ -103,11 +99,19 @@ def _kernel(a_ref, bp_ref, *refs, n_tiles: int, k_tiles: int, m_tiles: int,
     if with_colcheck:
         # Eq.-1 colsum matvec fused into the same pass: colsum of the A
         # tile (zero-padded rows contribute 0) times the B' tile.  Runs on
-        # the tiles already in VMEM — no extra HBM reads.
-        asum = jnp.sum(a_ref[...].astype(jnp.int32), axis=0)
-        contrib = jax.lax.dot_general(
-            asum, bp_ref[...].astype(jnp.int32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        # the tiles already in VMEM — no extra HBM reads.  The MXU takes
+        # no int32 operands, so the colsum is split into base-128 int8
+        # digits, each an exact int8 x int8 -> int32 row-vector dot.
+        asum = jnp.sum(a_ref[...].astype(jnp.int32), axis=0, keepdims=True)
+        contrib = jnp.zeros((1, bn), jnp.int32)
+        for d in range(col_digits):
+            digit = asum if d == col_digits - 1 else asum & 127
+            contrib += jax.lax.dot_general(
+                digit.astype(jnp.int8), bp_ref[...],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32) << (7 * d)
+            asum = asum >> 7
+        contrib = contrib[0]
         colacc_ref[0, pl.ds(j * bn, bn)] += contrib
         if zero_point:
             # the unsigned colsum is colsum(A_s) + 128·m; add the constant
@@ -205,10 +209,15 @@ def abft_qgemm_pallas(a_q: jax.Array, b_packed: jax.Array, *,
     # when bn < LANE it spans several tiles but lane 0 of the *first* of them
     # carries the checksum, so we treat tile index n_tiles_c as "the" verify
     # tile and ignore the trailing zero tiles.
+    # base-128 digits of a bm-row int8 column sum (|sum| <= 128*bm), the
+    # top one signed: enough that the top digit fits int8
+    col_digits = 1
+    while 128 ** (col_digits - 1) < bm:
+        col_digits += 1
     kernel = functools.partial(
         _kernel, n_tiles=n_tiles_c + 1, k_tiles=k_tiles, m_tiles=m_tiles,
         mod=mod, zero_point=zero_point, valid_m=m,
-        with_colcheck=with_colcheck, bn=bn)
+        with_colcheck=with_colcheck, bn=bn, col_digits=col_digits)
 
     out_specs = [
         pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -244,7 +253,7 @@ def abft_qgemm_pallas(a_q: jax.Array, b_packed: jax.Array, *,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(m_semantics, "arbitrary", "arbitrary")),
         interpret=interpret,
     )(a_pad, bp_pad)

@@ -1,13 +1,14 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-``use_pallas`` selects the Pallas path (TPU target; ``interpret=True``
-executes the kernel body on CPU for validation) vs. the pure-XLA path (the
-op set the dry-run lowers — identical math, real HLO cost model).  On a CPU
-container the default is the XLA path; on TPU it is the Pallas path.
+``use_pallas`` selects the Pallas path vs. the pure-XLA path (identical
+math, real HLO cost model).  On a CPU host the default is the XLA path; on
+TPU it is the Pallas path.
 
 Dispatch precedence (all three wrappers): an EXPLICIT ``use_pallas``
 (True/False) always wins.  Only when it is None does ``interpret=True``
 (validate the kernel body on CPU) or a TPU backend select the Pallas path.
+Interpret mode is a CPU-only tool: on a TPU the kernels always compile
+with Mosaic, whatever ``interpret`` says.
 
 These wrappers are the operator surface the :mod:`repro.protect` adapters
 dispatch to — layer code should not call them directly.
@@ -26,10 +27,7 @@ from repro.kernels import ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _use_pallas(use_pallas: Optional[bool], interpret: bool) -> bool:
@@ -62,7 +60,7 @@ def abft_qgemm(a_q: jax.Array, b_packed: jax.Array, *,
     if _use_pallas(use_pallas, interpret):
         from repro.kernels.abft_qgemm import abft_qgemm_pallas
         return abft_qgemm_pallas(a_q, b_packed, bm=bm, bn=bn, bk=bk,
-                                 interpret=interpret or not _on_tpu(),
+                                 interpret=not _on_tpu(),
                                  with_colcheck=with_colcheck)
     c, err_rows = _ref.abft_qgemm_ref(a_q, b_packed)
     if not with_colcheck:
@@ -83,7 +81,7 @@ def abft_embedding_bag(table_q, alphas, betas, indices, rowsums,
     if _use_pallas(use_pallas, interpret):
         from repro.kernels.abft_embeddingbag import abft_eb_pallas
         r, rsum = abft_eb_pallas(table_q, alphas, betas, indices, weights,
-                                 interpret=interpret or not _on_tpu())
+                                 interpret=not _on_tpu())
         # ONE Eq. (5) definition for both paths (repro.core.verify_bags):
         # the kernel's fused rsum feeds the shared check, so rel_bound
         # semantics cannot drift between XLA and Pallas
@@ -99,5 +97,5 @@ def quantize_rows(x: jax.Array, *, use_pallas: Optional[bool] = None,
     """Per-row signed-int8 dynamic quantization. -> (q, alpha, beta)."""
     if _use_pallas(use_pallas, interpret):
         from repro.kernels.quantize_rows import quantize_rows_pallas
-        return quantize_rows_pallas(x, interpret=interpret or not _on_tpu())
+        return quantize_rows_pallas(x, interpret=not _on_tpu())
     return _ref.quantize_rows_ref(x)

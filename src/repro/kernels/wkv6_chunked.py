@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names this TPUCompilerParams; newer releases dropped the prefix.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, y_ref, sout_ref,
             s_ref, *, n_chunks: int, chunk: int):
@@ -116,7 +112,7 @@ def wkv_chunked_pallas(rh, kh, vh, lwh, u, state, *, chunk: int = 16,
             jax.ShapeDtypeStruct((bh, dh, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rc, kc, vc, lwc, u_bh, s0)

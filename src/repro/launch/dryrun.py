@@ -258,7 +258,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         # mesh: its shardings carry the mesh, and an ambient (Auto,Auto)
         # mesh conflicts with the (Manual,Auto) abstract mesh inside
         cm = contextlib.nullcontext() if (deferred and shape.kind ==
-                                          "train") else mesh
+                                          "train") else jax.set_mesh(mesh)
         with cm:
             if shape.kind == "train":
                 lowered, params_lp = build_train(model, shape, rules, mesh)

@@ -18,6 +18,8 @@ import argparse
 import collections
 import re
 
+import jax
+
 from repro.launch import dryrun as dr
 from repro.launch.costs import HloCostModel, _trip_count, parse_hlo
 from repro.launch.mesh import make_production_mesh
@@ -80,7 +82,7 @@ def main():
              else serve_rules(args.multi_pod))
     model = build_model(cfg, max_pos=max(shape.seq_len, 4096)
                         + cfg.meta_tokens + 1)
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             lowered, _ = dr.build_train(model, shape, rules, mesh)
         elif shape.kind == "prefill":

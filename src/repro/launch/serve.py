@@ -135,6 +135,7 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.device_count}")
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.registry import get_arch
     from repro.protect import (ProtectionPlan, default_plan,
                                unprotected_plan)
@@ -143,6 +144,7 @@ def main(argv=None) -> int:
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
     log = logging.getLogger("repro.serve")
+    enable_compile_cache()
 
     if args.no_abft and (args.plan is not None or args.tenant):
         ap.error("--no-abft conflicts with --plan/--tenant; start the "

@@ -51,6 +51,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.compile_cache import enable_compile_cache
+    from repro.configs import reduce_cfg
     from repro.configs.base import ShapeConfig
     from repro.configs.registry import get_arch
     from repro.data import make_dataset
@@ -66,13 +68,10 @@ def main():
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
     log = logging.getLogger("repro.train")
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                        "..", "..", "..", "tests"))
-        from helpers import reduce_cfg
         cfg = reduce_cfg(cfg)
     if args.accum > 1:
         cfg = dataclasses.replace(cfg, train_accum=args.accum)
@@ -96,7 +95,7 @@ def main():
     state_sh = shardings_of(state_lp, rules, mesh)
     batch_sh = shardings_of(model.input_specs(shape), rules, mesh)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         state = init_train_state(model, jax.random.key(0),
                                  compress=args.compress)
         state = jax.device_put(state, state_sh)

@@ -37,8 +37,10 @@ def init_dlrm(key, ex: DlrmExtras, quant: bool = True, dtype=jnp.float32,
     n_feat = ex.n_tables + 1
     inter_dim = ex.emb_dim + n_feat * (n_feat - 1) // 2
     top = _init_mlp_stack(k2, (inter_dim,) + ex.top_mlp, quant, dtype)
-    tables = jax.vmap(
-        lambda k: init_embedding_bag(k, rows, ex.emb_dim))(
+    # one table at a time: peak init memory is the stacked tables plus ONE
+    # table's temporaries (a vmap over all tables holds every table's)
+    tables = jax.lax.map(
+        lambda k: init_embedding_bag(k, rows, ex.emb_dim),
         jax.random.split(k3, ex.n_tables))
     tables = jax.tree.map(
         lambda p: LogicalParam(p.value, ("tables",) + p.axes), tables,

@@ -705,7 +705,7 @@ class ServingEngine:
         return out, dt
 
     def _record_slot(self, slot: Slot, telemetry: Telemetry,
-                     aborted: bool = False):
+                     aborted: bool = False, logits=None):
         req = slot.request
         telemetry.add_request(RequestRecord(
             rid=req.rid, tenant=req.tenant, kind=req.kind,
@@ -713,7 +713,7 @@ class ServingEngine:
             first_token_s=slot.first_token_s, finish_s=self.clock_s,
             prompt_len=req.prompt_len, tokens_out=slot.generated,
             queue_wait_s=slot.queue_wait_s, aborted=aborted,
-            tokens=getattr(slot, "token_ids", None),
+            tokens=getattr(slot, "token_ids", None), logits=logits,
             prefill_tokens=slot.prefill_tokens,
             shared_prefix_tokens=slot.shared_prefix_tokens))
 
@@ -1006,9 +1006,9 @@ class ServingEngine:
         dense = jnp.asarray(req.payload["dense"])
         bags = jnp.asarray(req.payload["bags"])
         aborted = False
-        metrics, dt = None, 0.0
+        logits, metrics, dt = None, None, 0.0
         try:
-            (_, metrics), dt = self._timed(
+            (logits, metrics), dt = self._timed(
                 lane.forward_fn, self.params, dense, bags)
         except Exception as e:          # noqa: BLE001 - abort policy only
             if not is_fault_abort(e):
@@ -1016,7 +1016,9 @@ class ServingEngine:
             self.clock_s += 1e-6
             aborted = True
         slot_like.first_token_s = None if aborted else self.clock_s
-        self._record_slot(slot_like, telemetry, aborted=aborted)
+        self._record_slot(slot_like, telemetry, aborted=aborted,
+                          logits=None if aborted
+                          else np.asarray(logits).tolist())
         self._step_event("dlrm", lane, dt, metrics, telemetry,
                          injected=injected,
                          errors_override=1 if aborted else None,

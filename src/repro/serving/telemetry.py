@@ -65,6 +65,7 @@ class RequestRecord:
     aborted: bool = False
     rejected: bool = False               # shed at the admission queue
     tokens: Optional[List[int]] = None   # emitted ids (soak ground truth)
+    logits: Optional[List[float]] = None  # dlrm lookup outputs
     #: prompt tokens this admission actually quantized at prefill vs
     #: served from already-resident shared prefix pages (paged KV lanes;
     #: contiguous lanes report the full bucket and zero shared)
@@ -96,6 +97,7 @@ class RequestRecord:
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
         d.pop("tokens")                  # bulky; kept host-side only
+        d.pop("logits")
         return d
 
 

@@ -15,24 +15,13 @@ import jax as _jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, manual_axes=None):
-    """Version-portable manual-sharding wrapper.
-
-    jax >= 0.5 exposes ``jax.shard_map(check_vma=..., axis_names=...)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(check_rep=...,
-    auto=...)`` where ``auto`` is the COMPLEMENT of the manual axis set.
-    Replication checking is disabled on both paths (our steps psum
-    explicitly).
-    """
+    """``jax.shard_map`` over ``manual_axes`` (default: every mesh axis),
+    with replication checking off (our steps psum explicitly)."""
     manual = set(manual_axes) if manual_axes is not None \
         else set(mesh.axis_names)
-    if hasattr(_jax, "shard_map"):
-        return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False,
-                              axis_names=manual)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - manual
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False,
+                          axis_names=manual)
 
 
 def make_data_mesh(n_shards: int, devices=None):

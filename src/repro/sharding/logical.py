@@ -121,46 +121,24 @@ def like_shardings(values_tree, spec, mesh: Mesh):
 
 # -------------------- in-function sharding constraints ----------------------
 
-def _context_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # pragma: no cover
-        return None
-
-
-def _manual_axes() -> set:
-    """Mesh axes currently under manual (shard_map) control."""
-    try:
-        from jax.sharding import get_abstract_mesh
-        am = get_abstract_mesh()
-        if am is None or am.empty:
-            return set()
-        return {name for name, t in zip(am.axis_names, am.axis_types)
-                if str(t) == "Manual"}
-    except Exception:  # pragma: no cover
-        return set()
-
-
 def constrain(x, axes: Sequence[Optional[str]], rules: Optional[dict]):
     """with_sharding_constraint by logical axes.
 
-    No-op when no rules are given or no mesh is active (smoke tests run
-    un-meshed on one device).  Inside a partial-manual shard_map, axes that
-    are Manual (e.g. the deferred-sync data axis) are dropped from the
-    spec — constraints only apply to the remaining auto axes.
+    No-op when no rules are given (smoke tests run un-meshed on one
+    device).  Rules without a mesh set by ``jax.set_mesh`` (or an
+    enclosing ``shard_map``) are an error: silently dropping the
+    constraint would hide a lost mesh.  Inside a partial-manual shard_map,
+    axes that are Manual (e.g. the deferred-sync data axis) are dropped
+    from the spec — constraints only apply to the remaining auto axes.
     """
     if rules is None:
         return x
-    mesh = _context_mesh()
-    if mesh is None:
-        return x
-    manual = _manual_axes()
-    mesh_shape = {name: size
-                  for name, size in zip(mesh.axis_names, mesh.devices.shape)
-                  if name not in manual}
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        raise ValueError("sharding rules given but no mesh is set; run "
+                         "under `with jax.set_mesh(mesh):`")
+    mesh_shape = {name: size for name, size, t in
+                  zip(mesh.axis_names, mesh.axis_sizes, mesh.axis_types)
+                  if t != jax.sharding.AxisType.Manual}
     spec = spec_for(x.shape, axes, rules, mesh_shape)
-    if manual:
-        return jax.lax.with_sharding_constraint(x, spec)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec)

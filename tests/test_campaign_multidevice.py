@@ -104,6 +104,14 @@ def test_resolve_device_count_falls_back_with_warning():
     assert resolve_device_count(1) == 1
 
 
+def test_resolve_device_count_strict_raises():
+    import jax
+    avail = jax.local_device_count()
+    with pytest.raises(RuntimeError, match="only"):
+        resolve_device_count(avail + 1, strict=True)
+    assert resolve_device_count(avail, strict=True) == avail
+
+
 def test_cell_mesh_clamps_to_available_devices():
     import jax
     if jax.local_device_count() > 1:

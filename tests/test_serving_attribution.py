@@ -101,10 +101,15 @@ def test_engine_obs_detection_events_carry_rids(engine):
     injections = [e for e in obs.bus if e.kind == "injection"]
     assert detections and injections
     assert "mlp.down" in injections[0].op
-    flagged = {ev.step: ev for ev in tel.steps if ev.errors > 0}
+    # one global step can hold several step events (a prefill and a
+    # decode); a detection blames the residents of the event that flagged
+    flagged = {}
+    for ev in tel.steps:
+        if ev.errors > 0:
+            flagged.setdefault(ev.step, []).append(ev.slot_rids)
     for e in detections:
         assert e.op and e.step in flagged
-        assert e.request_ids == flagged[e.step].slot_rids
+        assert e.request_ids in flagged[e.step]
         assert len(e.request_ids) >= 1
     # per-op error counters in the registry match the timeline totals
     totals = tel.fault_counters()

@@ -226,3 +226,64 @@ def test_unknown_tenant_rejected(engine):
     bad = chat_stream(1, tenants={"nosuch": 1.0}, rate_rps=1.0, seed=0)
     with pytest.raises(ValueError, match="unknown tenant"):
         engine.run(bad)
+
+
+def test_dlrm_pallas_and_xla_lanes_agree_and_catch_a_flip():
+    """One-shot DLRM lookups through the fused Pallas kernels (interpret
+    mode here) and through the XLA reference, on the same tables: equal
+    logits per request, no flag on the clean pass, and a bottom-MLP weight
+    flip detected — what ``chip_smoke.py`` checks on the chip, at a size
+    the CPU runs in seconds."""
+    import dataclasses
+
+    from repro.configs.dlrm import EXTRAS
+    from repro.serving import dlrm_stream
+
+    ex = dataclasses.replace(EXTRAS, n_tables=4, table_rows=4096,
+                             pooling=20, bottom_mlp=(64, 128),
+                             top_mlp=(64, 32, 1))
+    tenants = [
+        TenantSpec("pallas", ProtectionPlan.parse(
+            "*:policy=log,qgemm:scheme=pallas,embedding_bag:scheme=pallas",
+            name="pallas")),
+        TenantSpec("xla", ProtectionPlan.parse(
+            "*:policy=log,qgemm:scheme=unfused,embedding_bag:scheme=xla",
+            name="xla")),
+    ]
+    eng = ServingEngine(get_arch("dlrm"), tenants, dlrm_extras=ex, seed=0)
+    n = 3
+    base = dlrm_stream(n, tenants={"pallas": 1.0}, seed=0,
+                       lookup_batch=ex.batch, table_rows=ex.table_rows,
+                       n_tables=ex.n_tables, max_pool=ex.pooling)
+    twin = [dataclasses.replace(r, rid=r.rid + n, tenant="xla")
+            for r in base]
+    tel = eng.run(base + twin)
+    assert sum(ev.errors for ev in tel.steps) == 0
+    recs = {r.rid: r for r in tel.requests}
+    for r in base:
+        got = np.asarray(recs[r.rid].logits)
+        assert got.shape == (ex.batch,) and np.isfinite(got).all()
+        np.testing.assert_allclose(got, recs[r.rid + n].logits,
+                                   rtol=1e-5, atol=1e-5)
+    assert "logits" not in recs[0].to_dict()
+
+    eng.reset_state()
+    tel = eng.run(base, inject=[FaultInjection(step=1, victim="bottom",
+                                               seed=0)])
+    (inj,) = tel.summary()["faults"]["injections"]
+    assert inj["detected"]
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    r = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       cwd=root, capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr
+    assert '"ok"' not in r.stdout

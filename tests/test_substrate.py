@@ -217,6 +217,36 @@ def test_straggler_host_attribution():
     assert ev["slow_hosts"] == [2]
 
 
+# ---------------------------- compile cache ----------------------------------
+
+@pytest.fixture
+def cache_config():
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_defaults_to_checkout(cache_config, monkeypatch):
+    from repro.compile_cache import CHECKOUT, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = enable_compile_cache()
+    assert path == str(CHECKOUT / ".jax_cache")
+    assert (CHECKOUT / "src" / "repro" / "compile_cache.py").exists()
+    assert jax.config.jax_compilation_cache_dir == path
+    assert enable_compile_cache() == path      # fixed: same on every call
+
+
+def test_compile_cache_env_dir_wins_untouched(cache_config, monkeypatch,
+                                              tmp_path):
+    from repro.compile_cache import enable_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+
+
 # ------------------------------ elastic --------------------------------------
 
 def test_plan_remesh_shrinks_data_axis():
