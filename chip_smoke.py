@@ -118,7 +118,7 @@ def fit_table_rows(ex, bytes_limit: int) -> tuple[int, str]:
                   f"1 GiB spare); widths unchanged")
 
 
-def dlrm_phase(dev, *, n_requests: int, seed: int, ex=None) -> None:
+def dlrm_phase(dev, *, n_requests: int, seed: int) -> None:
     import jax
     import numpy as np
 
@@ -128,7 +128,7 @@ def dlrm_phase(dev, *, n_requests: int, seed: int, ex=None) -> None:
     from repro.serving import (FaultInjection, ServingEngine, TenantSpec,
                                dlrm_stream)
 
-    ex = EXTRAS if ex is None else ex
+    ex = EXTRAS
     stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit")
     check(limit is not None, "device reports bytes_limit")
@@ -220,7 +220,7 @@ def dlrm_phase(dev, *, n_requests: int, seed: int, ex=None) -> None:
 
 # --------------------------- four chips: checked psum ------------------------
 
-def train_pair(cfg, *, steps: int, lr: float = LR):
+def train_pair(cfg, *, steps: int):
     """(model, local_step, flip_check) — one replica's train step whose
     gradient all-reduce is the checked int8 psum over axis ``data``, and a
     probe that runs that psum on a clean and a flipped payload."""
@@ -236,7 +236,7 @@ def train_pair(cfg, *, steps: int, lr: float = LR):
     model = build_model(cfg, max_pos=cfg.attn_chunk)
     ctx = Ctx(quant=False, compute_dtype=jnp.float32)
     step = make_train_step(model, ctx, compress=True, axis_name="data",
-                           n_replicas=4, peak_lr=lr, warmup=0,
+                           n_replicas=4, peak_lr=LR, warmup=0,
                            total_steps=100 * steps)
 
     def local_step(state, comm, batch):
@@ -268,7 +268,7 @@ def train_pair(cfg, *, steps: int, lr: float = LR):
 
 
 def four_chip_programs(local_step, flip_check, devs):
-    """(step4, flip4, step1): the replica step and the payload probe as
+    """(mesh, step4, flip4, step1): the replica step and the payload probe as
     one program over a 4-chip ``("data",)`` mesh, and the same replica
     arithmetic on ONE chip — a vmap over the replica axis, whose psum is a
     reduction over the mapped axis."""
@@ -301,7 +301,7 @@ def four_chip_programs(local_step, flip_check, devs):
 
 
 def four_chip_phase(*, steps: int, layers: int, seq: int, batch: int,
-                    seed: int, cfg=None) -> None:
+                    seed: int) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -315,9 +315,8 @@ def four_chip_phase(*, steps: int, layers: int, seq: int, batch: int,
 
     resolve_device_count(4, strict=True)
     devs = jax.devices()[:4]
-    if cfg is None:
-        cfg = dataclasses.replace(get_arch("granite-moe-3b-a800m"),
-                                  n_layers=layers, train_accum=1)
+    cfg = dataclasses.replace(get_arch("granite-moe-3b-a800m"),
+                              n_layers=layers, train_accum=1)
     model, local_step, flip_check = train_pair(cfg, steps=steps)
     log(f"model: {cfg.name} at d_model {cfg.d_model}, {cfg.n_experts} "
         f"experts x d_ff {cfg.d_ff}, vocab {cfg.vocab}, {cfg.n_layers} "
