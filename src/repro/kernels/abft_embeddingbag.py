@@ -133,7 +133,9 @@ def _fold_tables(interpret: bool):
                            idx.reshape((-1,) + idx.shape[2:]))
             return ((r.reshape(axis_size, n_tables, bags, -1),
                      rsum.reshape(axis_size, n_tables, bags)), (True, True))
-        # one table set shared by every mapped member: stack them as bags
+        # one table set shared by every mapped member (the campaign's
+        # eb_pallas clean trials vmap keys over one table): stack them as
+        # bags
         coef = coef.transpose(1, 2, 0, 3, 4).reshape(
             n_tables, 3, axis_size * bags, pool)
         idx = idx.transpose(1, 0, 2, 3).reshape(

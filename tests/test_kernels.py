@@ -1,4 +1,6 @@
 """Pallas kernels vs. pure-jnp oracles — shape/dtype sweeps (interpret=True)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -289,6 +291,37 @@ def test_eb_kernel_matches_ref(rng, rows, d, bags, pool):
     idx = jnp.asarray(rng.integers(0, rows, size=(bags, pool)), jnp.int32)
     r_ref, rsum_ref = kref.abft_eb_ref(t, al, be, idx)
     r, rsum = abft_eb_pallas(t, al, be, idx, interpret=True)
+    np.testing.assert_allclose(np.asarray(r), np.asarray(r_ref), rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(rsum), np.asarray(rsum_ref),
+                               rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("layout", ["tables", "shared"])
+def test_eb_kernel_vmap_matches_ref(rng, layout):
+    """Both branches of the kernel's vmap rule: stacked tables, each with
+    its own bags (the DLRM table vmap), and one table shared by every
+    mapped bag set (the campaign's clean trials).  Indices avoid multiples
+    of ROW_GROUP so the row is picked from inside its fetched group."""
+    from repro.kernels.abft_embeddingbag import ROW_GROUP
+    n, rows, d, bags, pool = 3, 200, 64, 5, 9
+    stacked = layout == "tables"
+    lead = (n,) if stacked else ()
+    t = jnp.asarray(rng.integers(-128, 128, size=lead + (rows, d)), jnp.int8)
+    al = jnp.asarray(rng.uniform(0.001, 0.1, size=lead + (rows,)),
+                     jnp.float32)
+    be = jnp.asarray(rng.uniform(-0.5, 0.5, size=lead + (rows,)),
+                     jnp.float32)
+    idx = rng.integers(0, rows // ROW_GROUP, size=(n, bags, pool)) \
+        * ROW_GROUP + rng.integers(1, ROW_GROUP, size=(n, bags, pool))
+    idx = jnp.asarray(idx, jnp.int32)
+    axes = (0, 0, 0, 0) if stacked else (None, None, None, 0)
+
+    def vmapped(fn):
+        return jax.vmap(fn, in_axes=axes)(t, al, be, idx)
+
+    r_ref, rsum_ref = vmapped(kref.abft_eb_ref)
+    r, rsum = vmapped(functools.partial(abft_eb_pallas, interpret=True))
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_ref), rtol=1e-5,
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(rsum), np.asarray(rsum_ref),
