@@ -5,7 +5,8 @@ Host-side primitives shared by campaign, training, and serving:
 * :class:`EventBus` + :class:`FaultEvent` (``events.py``) — typed fault
   events with JSONL export and schema validation;
 * :class:`Tracer` (``trace.py``) — timed spans with Chrome/Perfetto
-  trace export;
+  trace export; :class:`span` times a host block on the profiler's clock
+  too, so it lines up with the device's ops in a ``jax.profiler`` trace;
 * :class:`MetricsRegistry` (``metrics.py``) — counters/gauges/histograms
   with Prometheus-text and JSON exporters;
 * :class:`Monitor` (``monitor.py``) + :mod:`repro.obs.health` — live
@@ -42,7 +43,7 @@ from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                MetricsRegistry, default_registry)
 from repro.obs.monitor import (AlertFiring, AlertRule, EngineResponses,
                                Monitor, default_rules)
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import Span, Tracer, span
 
 
 @dataclasses.dataclass
@@ -262,7 +263,7 @@ def replay(events: Union[str, EventBus, Iterable[FaultEvent]],
 
 __all__ = ["Observability", "replay", "EventBus", "FaultEvent",
            "events_from_metrics", "validate_event", "EVENT_SCHEMA",
-           "EVENT_SCHEMA_VERSION", "EVENT_KINDS", "Tracer", "Span",
+           "EVENT_SCHEMA_VERSION", "EVENT_KINDS", "Tracer", "Span", "span",
            "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "default_registry", "DEFAULT_BUCKETS",
            "Monitor", "AlertRule", "AlertFiring", "EngineResponses",

@@ -7,10 +7,15 @@ serializes them as Chrome Trace Event JSON (``"ph": "X"`` complete
 events), which both ``chrome://tracing`` and https://ui.perfetto.dev
 open directly.  Track assignment: ``pid`` 0, one ``tid`` per category,
 so campaign phases and serving steps land on separate rows.
+
+:class:`span` is the one way to time a block of host code.  It always
+opens a ``jax.profiler.TraceAnnotation`` (inactive, and nearly free,
+unless a profiler session runs), so the block shows on the profiler's
+host clock beside the device's ops; given a :class:`Tracer`, it also
+records the block there.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import time
@@ -38,15 +43,9 @@ class Tracer:
     def now_s(self) -> float:
         return self._clock() - self._epoch
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "phase", **args):
-        """Time a ``with`` block as one span."""
-        t0 = self.now_s()
-        try:
-            yield self
-        finally:
-            self.add_span(name, cat=cat, start_s=t0,
-                          dur_s=self.now_s() - t0, **args)
+    def span(self, name: str, cat: str = "phase", **args) -> "span":
+        """Time a ``with`` block as one span (see :class:`span`)."""
+        return span(name, self, cat=cat, **args)
 
     def add_span(self, name: str, *, cat: str = "phase", start_s: float,
                  dur_s: float, **args) -> Span:
@@ -89,4 +88,34 @@ class Tracer:
         return len(self.spans)
 
 
-__all__ = ["Span", "Tracer"]
+class span:                                    # noqa: N801 - used as a verb
+    """``with span(name, tracer):`` times the block as a profiler host
+    event and, when ``tracer`` is given, as a :class:`Span` of ``cat`` on
+    the tracer's clock.  The block sees the tracer (or None).
+
+    A class rather than a generator context manager, which would double
+    its cost: the serving engine opens five a request."""
+
+    __slots__ = ("name", "tracer", "cat", "args", "_ann", "_t0")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None, *,
+                 cat: str = "host", **args):
+        self.name, self.tracer, self.cat, self.args = name, tracer, cat, args
+
+    def __enter__(self) -> Optional[Tracer]:
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self.tracer is not None:
+            self._t0 = self.tracer.now_s()
+        return self.tracer
+
+    def __exit__(self, *exc) -> None:
+        if self.tracer is not None:
+            self.tracer.add_span(self.name, cat=self.cat, start_s=self._t0,
+                                 dur_s=self.tracer.now_s() - self._t0,
+                                 **self.args)
+        self._ann.__exit__(*exc)
+
+
+__all__ = ["Span", "Tracer", "span"]

@@ -24,6 +24,16 @@ measured wall time of the jitted steps (compiles are excluded via
 :meth:`warmup`), so SLO percentiles reflect real compute under the
 chosen protection plans.
 
+Every call of :meth:`ServingEngine.run` is timed as host spans
+(:class:`repro.obs.span`) on the profiler's clock, so a ``jax.profiler``
+trace shows what the host did around each of the device's ops:
+``serving/run`` (the whole call), ``serving/upload`` (a DLRM request's
+arrays sent to the device), ``serving/dispatch`` (launching a jitted
+step), ``serving/wait`` (blocked until it finishes) and
+``serving/readback`` (its outputs and FaultReport counters read back).
+Each blocking device-to-host read is counted on the step's
+:class:`StepEvent` (``host_fetches``).
+
 Fault injection is first-class: a :class:`FaultInjection` flips a bit in
 a plan-path-addressed weight leaf right before a chosen step and — unless
 ``persistent`` — restores the clean weight right after it, so a
@@ -40,6 +50,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import span
 from repro.serving.batcher import ContinuousBatcher, Slot
 from repro.serving.queue import AdmissionQueue
 from repro.serving.telemetry import (InjectionRecord, RequestRecord,
@@ -81,19 +92,25 @@ def tenant_weights(tenants: Sequence[TenantSpec]) -> Dict[str, float]:
     return {t.name: t.weight for t in tenants}
 
 
-def _counters_of(metrics: dict) -> tuple:
-    """(per-op int counters, total residual errors) from step metrics."""
+def _counters_of(metrics: dict, fetch) -> tuple:
+    """(per-op int counters, total residual errors) from step metrics,
+    each read to the host as ``fetch(int, value)``."""
     from repro.core.policy import op_kinds
+
+    def read(key):
+        v = metrics.get(key)
+        return 0 if v is None else fetch(int, v)
+
     out: Dict[str, int] = {}
     errors = 0
     for k in op_kinds():
-        c = int(metrics.get(f"abft/{k}_checks", 0))
-        e = int(metrics.get(f"abft/{k}_errors", 0))
+        c = read(f"abft/{k}_checks")
+        e = read(f"abft/{k}_errors")
         out[f"{k}_checks"] = c
         out[f"{k}_errors"] = e
         errors += e
-    out["retries"] = int(metrics.get("abft/retries", 0))
-    out["corrections"] = int(metrics.get("abft/corrections", 0))
+    out["retries"] = read("abft/retries")
+    out["corrections"] = read("abft/corrections")
     return out, errors
 
 
@@ -175,6 +192,10 @@ class ServingEngine:
         self._adapt = None
         #: lane keys whose plan was already escalated (one-way per engine)
         self._escalated = set()
+        #: blocking device-to-host reads since the last step event
+        self._fetches = 0
+        #: tracer time at which the current step was dispatched
+        self._dispatch_s = 0.0
 
         #: PagingConfig | None — paged, prefix-shared, per-page-checksummed
         #: KV mode.  Prompts round up to page-multiple buckets, slots hold
@@ -547,8 +568,9 @@ class ServingEngine:
         from repro.obs import FaultEvent
         self._obs.tracer.add_span(
             f"paged_{action}", cat="paging",
-            start_s=self.clock_s - dur_s, dur_s=dur_s, lane=lane.key,
-            step=self.global_step, **attrs)
+            start_s=self._obs.tracer.now_s() - dur_s, dur_s=dur_s,
+            lane=lane.key, step=self.global_step,
+            clock_s=self.clock_s - dur_s, **attrs)
         self._obs.registry.counter(
             "repro_paging_ops_total",
             "paged-KV lifecycle operations by action and lane").inc(
@@ -696,13 +718,41 @@ class ServingEngine:
 
     # ------------------------------ engine steps -----------------------------
 
+    @property
+    def _tracer(self):
+        """The bound Observability's tracer, else None."""
+        return None if self._obs is None else self._obs.tracer
+
     def _timed(self, fn, *args):
+        """Run one jitted step to completion: -> (outputs, wall seconds
+        from dispatch until the device finished)."""
         import jax
+        tracer = self._tracer
+        if tracer is not None:
+            self._dispatch_s = tracer.now_s()
         t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
+        with span("serving/dispatch", tracer):
+            out = fn(*args)
+        with span("serving/wait", tracer):
+            out = jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         self.clock_s += dt
         return out, dt
+
+    def _fetch(self, read, x):
+        """``read(x)``: one blocking device-to-host read (``int`` of a
+        scalar, ``np.asarray`` of an array), counted on the next step
+        event's ``host_fetches``."""
+        self._fetches += 1
+        return read(x)
+
+    def _readback(self, metrics, *outs) -> tuple:
+        """A step's blocking reads under one span: each of ``outs``, then
+        the FaultReport counters -> (host arrays, (counters, errors))."""
+        with span("serving/readback", self._tracer):
+            host = tuple(self._fetch(np.asarray, x) for x in outs)
+            counters = _counters_of(metrics, self._fetch)
+        return host, counters
 
     def _record_slot(self, slot: Slot, telemetry: Telemetry,
                      aborted: bool = False, logits=None):
@@ -720,26 +770,36 @@ class ServingEngine:
     def _step_event(self, kind: str, lane: _Lane, dt: float, metrics,
                     telemetry: Telemetry, injected: bool = False,
                     errors_override: Optional[int] = None,
-                    slot_rids: tuple = ()):
-        counters, errors = (_counters_of(metrics) if metrics is not None
-                            else ({}, 0))
+                    slot_rids: tuple = (), counters=None):
+        """Record one step.  ``counters`` is the (counters, errors) pair a
+        caller already read back; otherwise ``metrics`` are read here."""
+        if counters is None:
+            counters = (({}, 0) if metrics is None
+                        else self._readback(metrics)[1])
+        counters, errors = counters
         if errors_override is not None:
             errors = errors_override
+        fetches, self._fetches = self._fetches, 0
         telemetry.add_step(StepEvent(
             step=self.global_step, t_s=self.clock_s, kind=kind,
             lane=lane.key, duration_s=dt,
             occupancy=lane.batcher.occupancy(),
             queue_depth=self.queue.depth(), counters=counters,
             errors=errors, injected=injected,
-            slot_rids=tuple(slot_rids)))
+            slot_rids=tuple(slot_rids), host_fetches=fetches))
         if self._obs is not None:
             self._obs.tracer.add_span(
-                kind, cat="serving", start_s=self.clock_s - dt, dur_s=dt,
+                kind, cat="serving", start_s=self._dispatch_s, dur_s=dt,
                 lane=lane.key, step=self.global_step,
-                occupancy=lane.batcher.occupancy())
+                occupancy=lane.batcher.occupancy(),
+                clock_s=self.clock_s - dt)
             self._obs.registry.counter(
                 "repro_steps_total", "engine steps by kind").inc(
                     1, kind=kind, source="serving.engine")
+            self._obs.registry.counter(
+                "repro_host_fetches_total",
+                "blocking device-to-host reads by engine step kind").inc(
+                    fetches, kind=kind, source="serving.engine")
             self._obs.registry.histogram(
                 "repro_step_duration_ms",
                 "engine step wall duration").observe(
@@ -799,10 +859,12 @@ class ServingEngine:
         slot.pos = self._prefill_pos()
         slot.generated = 1
         slot.first_token_s = self.clock_s
-        slot.token_ids = [int(tok[0])]
+        (tok_host,), counters = self._readback(metrics, tok)
+        slot.token_ids = [int(tok_host[0])]
         slot.prefill_tokens = self.max_prompt   # full fixed-slot bucket
         self._step_event("prefill", lane, dt, metrics, telemetry,
-                         injected=injected, slot_rids=(req.rid,))
+                         injected=injected, slot_rids=(req.rid,),
+                         counters=counters)
 
     def _do_prefill_paged(self, lane: _Lane, slot: Slot,
                           telemetry: Telemetry, injected: bool):
@@ -851,7 +913,8 @@ class ServingEngine:
         slot.pos = bucket
         slot.generated = 1
         slot.first_token_s = self.clock_s
-        slot.token_ids = [int(tok[0])]
+        (tok_host,), counters = self._readback(metrics, tok)
+        slot.token_ids = [int(tok_host[0])]
         slot.bucket = bucket
         slot.prefill_tokens, slot.shared_prefix_tokens = plan.tokens(p)
         self._paging_event("admit", lane, slot=slot.index, rid=req.rid,
@@ -859,7 +922,8 @@ class ServingEngine:
                            shared_pages=plan.shared_pages,
                            new_pages=plan.new_pages)
         self._step_event("prefill", lane, dt, metrics, telemetry,
-                         injected=injected, slot_rids=(req.rid,))
+                         injected=injected, slot_rids=(req.rid,),
+                         counters=counters)
         self._publish_paging(lane)
 
     def _do_decode(self, lane: _Lane, telemetry: Telemetry,
@@ -886,16 +950,18 @@ class ServingEngine:
         lane.cache = cache
         lane.tokens = tok
         lane.pos = lane.pos + 1
-        tok_host = np.asarray(tok)
+        (tok_host,), counters = self._readback(metrics, tok)
         for slot in lane.batcher.active_slots():
             slot.generated += 1
             slot.pos += 1
             slot.token_ids.append(int(tok_host[slot.index]))
         errors = self._step_event("decode", lane, dt, metrics, telemetry,
-                                  injected=injected, slot_rids=resident)
+                                  injected=injected, slot_rids=resident,
+                                  counters=counters)
         if lane.pager is not None and errors > 0:
             policy = lane.plan.resolve("kv_cache_paged", "attn").policy
-            if int(metrics.get("abft/kv_cache_paged_errors", 0)) > 0 \
+            paged = metrics.get("abft/kv_cache_paged_errors")
+            if paged is not None and self._fetch(int, paged) > 0 \
                     and policy != "log":
                 self._paged_repair(lane, telemetry, policy)
         for slot in lane.batcher.retire_finished():
@@ -937,7 +1003,8 @@ class ServingEngine:
         pager = lane.pager
         t0 = time.perf_counter()
         flags = lane.scrub_fn(lane.cache, lane.pos)
-        bad = np.asarray(flags["k"]) + np.asarray(flags["v"])
+        bad = (self._fetch(np.asarray, flags["k"])
+               + self._fetch(np.asarray, flags["v"]))
         self._paging_event("scrub_cache", lane,
                            dur_s=time.perf_counter() - t0,
                            flagged=int((bad > 0).sum()), policy=policy)
@@ -1003,10 +1070,11 @@ class ServingEngine:
         from repro.core.policy import is_fault_abort
 
         req = slot_like.request
-        dense = jnp.asarray(req.payload["dense"])
-        bags = jnp.asarray(req.payload["bags"])
+        with span("serving/upload", self._tracer):
+            dense = jnp.asarray(req.payload["dense"])
+            bags = jnp.asarray(req.payload["bags"])
         aborted = False
-        logits, metrics, dt = None, None, 0.0
+        metrics, host_logits, counters, dt = None, None, None, 0.0
         try:
             (logits, metrics), dt = self._timed(
                 lane.forward_fn, self.params, dense, bags)
@@ -1015,14 +1083,16 @@ class ServingEngine:
                 raise
             self.clock_s += 1e-6
             aborted = True
+        else:
+            (host_logits,), counters = self._readback(metrics, logits)
+            host_logits = host_logits.tolist()
         slot_like.first_token_s = None if aborted else self.clock_s
         self._record_slot(slot_like, telemetry, aborted=aborted,
-                          logits=None if aborted
-                          else np.asarray(logits).tolist())
+                          logits=host_logits)
         self._step_event("dlrm", lane, dt, metrics, telemetry,
                          injected=injected,
                          errors_override=1 if aborted else None,
-                         slot_rids=(req.rid,))
+                         slot_rids=(req.rid,), counters=counters)
 
     def reset_state(self) -> None:
         """Fresh run state (clock, queue, lanes) with compiled steps kept —
@@ -1174,44 +1244,49 @@ class ServingEngine:
         an op ``threshold=adaptive`` get one FP-budget controller per
         (op, tenant) which reads the monitor's Wilson flag-rate estimate
         each iteration and rewrites the lane's ``rel_bound`` (plan
-        rewrite + re-jit) when it moves; requires ``monitor``."""
-        telemetry = telemetry if telemetry is not None else Telemetry()
+        rewrite + re-jit) when it moves; requires ``monitor``.
+
+        The call is one ``serving/run`` span (recorded in ``obs``'s tracer
+        too), holding the steps' ``serving/*`` spans."""
         if adapt is not None and monitor is None:
             raise ValueError("adapt= needs monitor= (its sensor)")
         if monitor is not None and obs is None:
             from repro.obs import Observability
             obs = Observability.create()
-        self._obs = obs
-        self._monitor = monitor
-        if monitor is not None:
-            monitor.bind(obs)
-        self._adapt = adapt
-        if adapt is not None:
-            adapt.bind(obs)
-            self._register_adaptive()
-        pending = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
-        for r in pending:
-            if r.tenant not in self._lane_of:
-                raise ValueError(f"request {r.rid} names unknown tenant "
-                                 f"{r.tenant!r}; have "
-                                 f"{sorted(self._lane_of)}")
-        injections = sorted(inject or [], key=lambda i: i.step)
-        inj_i = 0
-        if warmup:
-            self.warmup(pending[0] if pending else None)
-
-        try:
-            out = self._run_loop(pending, injections, inj_i, telemetry,
-                                 max_iterations)
+        with span("serving/run", None if obs is None else obs.tracer):
+            telemetry = telemetry if telemetry is not None else Telemetry()
+            self._fetches = 0
+            self._obs = obs
+            self._monitor = monitor
             if monitor is not None:
-                out.monitor = monitor.summary()
+                monitor.bind(obs)
+            self._adapt = adapt
             if adapt is not None:
-                out.thresholds = adapt.summary()
-            return out
-        finally:
-            self._obs = None
-            self._monitor = None
-            self._adapt = None
+                adapt.bind(obs)
+                self._register_adaptive()
+            pending = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
+            for r in pending:
+                if r.tenant not in self._lane_of:
+                    raise ValueError(f"request {r.rid} names unknown tenant "
+                                     f"{r.tenant!r}; have "
+                                     f"{sorted(self._lane_of)}")
+            injections = sorted(inject or [], key=lambda i: i.step)
+            inj_i = 0
+            if warmup:
+                self.warmup(pending[0] if pending else None)
+
+            try:
+                out = self._run_loop(pending, injections, inj_i, telemetry,
+                                     max_iterations)
+                if monitor is not None:
+                    out.monitor = monitor.summary()
+                if adapt is not None:
+                    out.thresholds = adapt.summary()
+                return out
+            finally:
+                self._obs = None
+                self._monitor = None
+                self._adapt = None
 
     def _run_loop(self, pending, injections, inj_i, telemetry,
                   max_iterations) -> Telemetry:

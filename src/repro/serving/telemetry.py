@@ -26,8 +26,9 @@ PCTS = (50.0, 95.0, 99.0)
 
 #: bump when to_dict() gains/renames fields — the serve CLI --json output
 #: and the soak artifacts carry this so downstream parsers can dispatch
-#: (v2: per-request/per-tenant prefill_tokens + shared_prefix_tokens)
-TELEMETRY_SCHEMA_VERSION = 2
+#: (v2: per-request/per-tenant prefill_tokens + shared_prefix_tokens;
+#: v3: per-step host_fetches + summary total)
+TELEMETRY_SCHEMA_VERSION = 3
 
 
 def percentiles_ms(xs_s: List[float]) -> Dict[str, float]:
@@ -117,6 +118,11 @@ class StepEvent:
     #: the attribution join key (prefill: the admitted request; decode:
     #: every active slot; abort: the drained slots)
     slot_rids: Tuple[int, ...] = ()
+    #: blocking device-to-host reads the engine made for this step (its
+    #: outputs and FaultReport counters; a paged scrub after the previous
+    #: step lands here too).  Reads an attached Observability makes of the
+    #: counters are not the engine's and are not counted.
+    host_fetches: int = 0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -245,6 +251,7 @@ class Telemetry:
         return {
             "requests": len(self.requests),
             "steps": len(self.steps),
+            "host_fetches": sum(ev.host_fetches for ev in self.steps),
             "span_s": span,
             "throughput_tok_s": tokens / span if span > 0 else 0.0,
             "queue_depth_max": max(depths, default=0),
