@@ -6,7 +6,8 @@ we provide the TPU-native equivalents:
 - :mod:`repro.kernels.abft_qgemm`        — int8 GEMM with lane-aligned
   checksum block and verification fused in the epilogue (zero extra HBM
   traffic for the verify pass — beyond the paper's cache-resident re-read).
-- :mod:`repro.kernels.abft_embeddingbag` — scalar-prefetch gather + bag-sum
+- :mod:`repro.kernels.abft_embeddingbag` — gather + bag-sum from a ring of
+  in-flight row DMAs (scalar-prefetched indices, many bags a grid step),
   with the Eq. 5 row-sum accumulated in the same pass.
 - :mod:`repro.kernels.quantize_rows`     — per-row dynamic activation
   quantization feeding the GEMM.

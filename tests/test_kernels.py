@@ -9,7 +9,7 @@ import pytest
 from repro.core.abft_gemm import encode_weight_checksum, pack_encoded_b
 from repro.core.inject import flip_bit
 from repro.kernels import ref as kref
-from repro.kernels.abft_embeddingbag import abft_eb_pallas
+from repro.kernels.abft_embeddingbag import RING, abft_eb_pallas
 from repro.kernels.abft_qgemm import abft_qgemm_pallas
 from repro.kernels.quantize_rows import quantize_rows_pallas
 from repro.kernels import ops
@@ -280,15 +280,32 @@ EB_SHAPES = [
     (1024, 64, 10, 100),
     (512, 128, 2, 7),
     (100, 16, 1, 1),
+    # pool 1, many bags: several bag blocks, the last one partly full
+    (4000, 32, 2500, 1),
+    # d = 64, 21 bags in blocks of 8 (the last holds 5), pool > RING
+    (1000, 64, 21, 100),
+    # a pool more than twice the DMA ring, rows % 8 != 0
+    (203, 128, 3, 2 * RING + 3),
+    # a table smaller than one row group: every row from the tail group
+    (5, 32, 6, 5),
 ]
+
+
+def _eb_inputs(rng, lead, rows, d):
+    t = jnp.asarray(rng.integers(-128, 128, size=lead + (rows, d)), jnp.int8)
+    al = jnp.asarray(rng.uniform(0.001, 0.1, size=lead + (rows,)),
+                     jnp.float32)
+    be = jnp.asarray(rng.uniform(-0.5, 0.5, size=lead + (rows,)),
+                     jnp.float32)
+    return t, al, be
 
 
 @pytest.mark.parametrize("rows,d,bags,pool", EB_SHAPES)
 def test_eb_kernel_matches_ref(rng, rows, d, bags, pool):
-    t = jnp.asarray(rng.integers(-128, 128, size=(rows, d)), jnp.int8)
-    al = jnp.asarray(rng.uniform(0.001, 0.1, size=rows), jnp.float32)
-    be = jnp.asarray(rng.uniform(-0.5, 0.5, size=rows), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, rows, size=(bags, pool)), jnp.int32)
+    t, al, be = _eb_inputs(rng, (), rows, d)
+    idx = rng.integers(0, rows, size=(bags, pool))
+    idx[0, 0] = rows - 1                      # the table's last row is read
+    idx = jnp.asarray(idx, jnp.int32)
     r_ref, rsum_ref = kref.abft_eb_ref(t, al, be, idx)
     r, rsum = abft_eb_pallas(t, al, be, idx, interpret=True)
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_ref), rtol=1e-5,
@@ -297,23 +314,26 @@ def test_eb_kernel_matches_ref(rng, rows, d, bags, pool):
                                rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("layout", ["tables", "shared"])
-def test_eb_kernel_vmap_matches_ref(rng, layout):
+@pytest.mark.parametrize("layout,rows,d,bags,pool", [
+    ("tables", 200, 64, 5, 9),
+    ("shared", 200, 64, 5, 9),
+    # pool 1: the shared layout stacks 3 x 700 bags into several blocks
+    ("tables", 2003, 128, 700, 1),
+    ("shared", 2003, 128, 700, 1),
+], ids=["tables", "shared", "tables-pool1", "shared-pool1"])
+def test_eb_kernel_vmap_matches_ref(rng, layout, rows, d, bags, pool):
     """Both branches of the kernel's vmap rule: stacked tables, each with
     its own bags (the DLRM table vmap), and one table shared by every
     mapped bag set (the campaign's clean trials).  Indices avoid multiples
-    of ROW_GROUP so the row is picked from inside its fetched group."""
+    of ROW_GROUP so the row is picked from inside its fetched group; each
+    bag set also reads the table's last row."""
     from repro.kernels.abft_embeddingbag import ROW_GROUP
-    n, rows, d, bags, pool = 3, 200, 64, 5, 9
+    n = 3
     stacked = layout == "tables"
-    lead = (n,) if stacked else ()
-    t = jnp.asarray(rng.integers(-128, 128, size=lead + (rows, d)), jnp.int8)
-    al = jnp.asarray(rng.uniform(0.001, 0.1, size=lead + (rows,)),
-                     jnp.float32)
-    be = jnp.asarray(rng.uniform(-0.5, 0.5, size=lead + (rows,)),
-                     jnp.float32)
+    t, al, be = _eb_inputs(rng, (n,) if stacked else (), rows, d)
     idx = rng.integers(0, rows // ROW_GROUP, size=(n, bags, pool)) \
         * ROW_GROUP + rng.integers(1, ROW_GROUP, size=(n, bags, pool))
+    idx[:, -1, -1] = rows - 1
     idx = jnp.asarray(idx, jnp.int32)
     axes = (0, 0, 0, 0) if stacked else (None, None, None, 0)
 
@@ -328,12 +348,27 @@ def test_eb_kernel_vmap_matches_ref(rng, layout):
                                rtol=1e-4, atol=1e-3)
 
 
-def test_eb_kernel_padding_and_weights(rng):
-    t = jnp.asarray(rng.integers(-128, 128, size=(64, 32)), jnp.int8)
-    al = jnp.asarray(rng.uniform(0.01, 0.1, size=64), jnp.float32)
-    be = jnp.asarray(rng.uniform(-0.1, 0.1, size=64), jnp.float32)
-    idx = jnp.asarray([[3, 9, -1, -1], [5, -1, -1, -1]], jnp.int32)
-    w = jnp.asarray([[1.0, 2.0, 9.9, 9.9], [0.5, 9.9, 9.9, 9.9]], jnp.float32)
+@pytest.mark.parametrize("rows,d,bags,pool", [
+    (64, 32, 2, 4),
+    # pool 1 with many bags, about a third of them empty (-1)
+    (1000, 128, 3000, 1),
+    # bags not a multiple of the bag block, rows % 8 != 0, pool > RING
+    (301, 64, 19, 45),
+])
+def test_eb_kernel_padding_and_weights(rng, rows, d, bags, pool):
+    """−1 slots contribute nothing whatever their weight; weights scale
+    each row (in the generated cases, also the table's last row)."""
+    t, al, be = _eb_inputs(rng, (), rows, d)
+    if bags == 2:            # the hand-written case
+        idx = np.asarray([[3, 9, -1, -1], [5, -1, -1, -1]])
+        w = np.asarray([[1.0, 2.0, 9.9, 9.9], [0.5, 9.9, 9.9, 9.9]])
+    else:
+        idx = rng.integers(0, rows, size=(bags, pool))
+        idx[rng.uniform(size=(bags, pool)) < 0.35] = -1
+        idx[-1, 0] = rows - 1
+        w = rng.uniform(-2.0, 2.0, size=(bags, pool))
+    idx = jnp.asarray(idx, jnp.int32)
+    w = jnp.asarray(w, jnp.float32)
     r_ref, _ = kref.abft_eb_ref(t, al, be, idx, w)
     r, _ = abft_eb_pallas(t, al, be, idx, w, interpret=True)
     np.testing.assert_allclose(np.asarray(r), np.asarray(r_ref), rtol=1e-5,

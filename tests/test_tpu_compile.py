@@ -82,25 +82,32 @@ def _eb_args(sharding, lead, rows, d, bags, pool):
             _shape(sharding, lead + (bags, pool), jnp.int32))
 
 
-@pytest.mark.parametrize("tables", [1, EXTRAS.n_tables],
-                         ids=["one_table", "vmap_tables"])
-def test_abft_eb_compiles_at_table_geometry(one_chip, tables):
-    """Table I: bags 10, pooling 100, 4M x 128 int8 rows per table.  The
-    tables are read in place: their device layout keeps their bytes (a
+@pytest.mark.parametrize("tables,rows,d,bags,pool", [
+    (1, EXTRAS.table_rows, EXTRAS.emb_dim, EXTRAS.batch, EXTRAS.pooling),
+    (EXTRAS.n_tables, EXTRAS.table_rows, EXTRAS.emb_dim, EXTRAS.batch,
+     EXTRAS.pooling),
+    # the DLRM repo's synthetic benchmark: 8 x 1M x 64, batch 128
+    (8, 1_000_000, 64, 128, 100),
+    # Criteo-style one-hot lookups: batch 20, pool 1
+    (EXTRAS.n_tables, EXTRAS.table_rows, EXTRAS.emb_dim, 20, 1),
+], ids=["one_table", "vmap_tables", "synth64", "onehot20"])
+def test_abft_eb_compiles_at_table_geometry(one_chip, tables, rows, d, bags,
+                                            pool):
+    """Table I: bags 10, pooling 100, 4M x 128 int8 rows per table (and the
+    d = 64 and pool-1 geometries the chip benchmark serves).  The tables
+    are read in place: their device layout keeps their bytes (a
     row-per-tile layout would take 4x), and no table-sized temporary
     appears.  Only the small per-row and per-slot arrays are tile-padded
     (the [26, rows] f32 scales pad 26 -> 32 rows)."""
     from repro.kernels.abft_embeddingbag import abft_eb_pallas
-    ex = EXTRAS
     fn = functools.partial(abft_eb_pallas, weights=None)
     lead = () if tables == 1 else (tables,)
     if tables > 1:
         fn = jax.vmap(fn)
-    args = _eb_args(one_chip, lead, ex.table_rows, ex.emb_dim, ex.batch,
-                    ex.pooling)
+    args = _eb_args(one_chip, lead, rows, d, bags, pool)
     mem = _compile(fn, *args).memory_analysis()
     in_bytes = sum(math.prod(a.shape) * a.dtype.itemsize for a in args)
-    table_bytes = ex.table_rows * ex.emb_dim
+    table_bytes = rows * d
     assert in_bytes <= mem.argument_size_in_bytes \
         < in_bytes + tables * table_bytes // 10
     assert mem.temp_size_in_bytes < table_bytes // 100
