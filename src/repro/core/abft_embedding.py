@@ -14,14 +14,18 @@ loose relative bound (1e-5 by default, §V-D).
 
 Batch layout: fixed-shape ``indices [bags, pool]`` padded with ``-1`` — the
 JAX-native analogue of the offsets layout in torch.nn.EmbeddingBag.  Padded
-slots contribute nothing to either side of Eq. 5.
+slots contribute nothing to either side of Eq. 5.  Tables whose bags differ
+in pool (DLRMv2's multi-hot features) come as a tuple with one ``[bags,
+pool_t]`` array a table, of rows of one table that holds them all; the
+results then gain a leading table axis.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 #: paper §V-D: loose relative bound to trade false positives for low-bit misses.
 REL_BOUND = 1e-5
@@ -59,17 +63,19 @@ def _gather_terms(table_q, alphas, betas, indices, weights):
 
 
 def embedding_bag(table_q: jax.Array, alphas: jax.Array, betas: jax.Array,
-                  indices: jax.Array,
-                  weights: Optional[jax.Array] = None) -> jax.Array:
+                  indices, weights=None) -> jax.Array:
     """The unprotected low-precision EB (§III-C): per-row dequant + bag sum."""
+    if isinstance(indices, (tuple, list)):
+        ws = (None,) * len(indices) if weights is None else weights
+        return jnp.stack([embedding_bag(table_q, alphas, betas, i, w)
+                          for i, w in zip(indices, ws)])
     rows, a, b, w = _gather_terms(table_q, alphas, betas, indices, weights)
     deq = a[..., None] * rows + b[..., None]             # [bags, pool, d]
     return jnp.sum(w[..., None] * deq, axis=1)           # [bags, d]
 
 
 def verify_bags(rsum: jax.Array, alphas: jax.Array, betas: jax.Array,
-                indices: jax.Array, rowsums: jax.Array, d: int,
-                weights: Optional[jax.Array] = None,
+                indices, rowsums: jax.Array, d: int, weights=None,
                 rel_bound: float = REL_BOUND) -> jax.Array:
     """The Eq. (5) compare: per-bag error flags from the EB output row sums.
 
@@ -86,25 +92,43 @@ def verify_bags(rsum: jax.Array, alphas: jax.Array, betas: jax.Array,
     would false-positive.  We scale the bound by Σ|terms| instead —
     strictly fewer false positives at the paper's rel_bound (its measured
     9.5% FP rate is this very effect), same high-bit sensitivity.
+
+    Ragged ``indices`` (a tuple, one table each) are checked in one pass
+    over every slot: ``rsum`` [T, bags] -> flags [T, bags].
     """
-    valid = indices >= 0
-    safe_idx = jnp.where(valid, indices, 0)
+    ragged = isinstance(indices, (tuple, list))
+    idx = indices
+    if ragged:
+        # every table's slots in one pass (as the Pallas wrapper gathers
+        # them, so that XLA gathers α and β once for both)
+        idx = jnp.concatenate([i.reshape(-1) for i in indices])
+        weights = None if weights is None else jnp.concatenate(
+            [w.reshape(-1) for w in weights])
+    valid = idx >= 0
+    safe_idx = jnp.where(valid, idx, 0)
     a = alphas[safe_idx]
     b = betas[safe_idx]
     w = (jnp.ones_like(a) if weights is None else weights)
     w = jnp.where(valid, w, 0.0)
     ct = rowsums[safe_idx].astype(jnp.float32)           # [bags, pool]
-    csum = jnp.sum(w * (a * ct + d * b), axis=-1)        # [bags]
-    mag = jnp.sum(jnp.abs(w) * (jnp.abs(a) * jnp.abs(ct)
-                                + d * jnp.abs(b)), axis=-1)
+    terms = w * (a * ct + d * b)
+    mags = jnp.abs(w) * (jnp.abs(a) * jnp.abs(ct) + d * jnp.abs(b))
+    if ragged:
+        # the flat slots back into each table's bags
+        at = np.cumsum([0] + [i.size for i in indices])
+        per_bag = lambda x: jnp.stack([                     # noqa: E731
+            x[at[t]:at[t + 1]].reshape(i.shape).sum(axis=-1)
+            for t, i in enumerate(indices)])
+    else:
+        per_bag = lambda x: jnp.sum(x, axis=-1)             # noqa: E731
+    csum, mag = per_bag(terms), per_bag(mags)            # [bags]
     tol = rel_bound * jnp.maximum(mag, 1.0)
     return jnp.abs(rsum - csum) > tol
 
 
 def abft_embedding_bag(table_q: jax.Array, alphas: jax.Array,
-                       betas: jax.Array, indices: jax.Array,
-                       rowsums: jax.Array,
-                       weights: Optional[jax.Array] = None,
+                       betas: jax.Array, indices,
+                       rowsums: jax.Array, weights=None,
                        rel_bound: float = REL_BOUND) -> AbftEbOut:
     """Algorithm 2: EB forward + Eq. (5) check per bag.
 

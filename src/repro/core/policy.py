@@ -69,17 +69,21 @@ class FaultReport:
     ``checks[name]`` / ``errors[name]`` count verified calls and residual
     (post-policy) errors per op kind; ``retries`` and ``corrections``
     aggregate the recompute/correct policy actions across all kinds.
+    ``eb_slots`` counts the EmbeddingBag slots (bag positions, −1 pads
+    included) the step's checked lookups ran, as the kernel's grid holds
+    them: work, fixed by the step's shapes.
     """
     checks: Dict[str, jax.Array]
     errors: Dict[str, jax.Array]
     retries: jax.Array
     corrections: jax.Array
+    eb_slots: jax.Array = 0
 
     def tree_flatten(self):
         names = tuple(sorted(self.checks))
         children = (tuple(self.checks[n] for n in names)
                     + tuple(self.errors[n] for n in names)
-                    + (self.retries, self.corrections))
+                    + (self.retries, self.corrections, self.eb_slots))
         return children, names
 
     @classmethod
@@ -87,7 +91,7 @@ class FaultReport:
         k = len(names)
         return cls(dict(zip(names, children[:k])),
                    dict(zip(names, children[k:2 * k])),
-                   children[2 * k], children[2 * k + 1])
+                   *children[2 * k:])
 
     # ------------------------------ queries ---------------------------------
 
@@ -107,6 +111,7 @@ class FaultReport:
             m[f"abft/{n}_errors"] = self.errors[n]
         m["abft/retries"] = self.retries
         m["abft/corrections"] = self.corrections
+        m["abft/eb_slots"] = self.eb_slots
         # legacy aliases (pre-protect metric names; gemm = int8 + float)
         m["abft/gemm_checks"] = self.gemm_checks
         m["abft/gemm_errors"] = self.gemm_errors
@@ -143,11 +148,11 @@ class FaultReport:
 def empty_report() -> FaultReport:
     z = _zero()
     return FaultReport({n: z for n in _OP_KINDS},
-                       {n: z for n in _OP_KINDS}, z, z)
+                       {n: z for n in _OP_KINDS}, z, z, z)
 
 
 def op_report(name: str, err_count, *, checks=1, retries=None,
-              corrections=None) -> FaultReport:
+              corrections=None, eb_slots=None) -> FaultReport:
     """A report with one op kind's counters set (all other kinds zero)."""
     if name not in _OP_KINDS:
         raise KeyError(f"unregistered op kind {name!r}; have {_OP_KINDS} "
@@ -159,6 +164,8 @@ def op_report(name: str, err_count, *, checks=1, retries=None,
         rep.retries = jnp.asarray(retries, jnp.int32)
     if corrections is not None:
         rep.corrections = jnp.asarray(corrections, jnp.int32)
+    if eb_slots is not None:
+        rep.eb_slots = jnp.asarray(eb_slots, jnp.int32)
     return rep
 
 
@@ -181,7 +188,8 @@ def merge_reports(*reports: FaultReport) -> FaultReport:
         {n: sum((r._get(r.checks, n) for r in reports), z) for n in names},
         {n: sum((r._get(r.errors, n) for r in reports), z) for n in names},
         sum((r.retries for r in reports), z),
-        sum((r.corrections for r in reports), z))
+        sum((r.corrections for r in reports), z),
+        sum((r.eb_slots for r in reports), z))
 
 
 def with_recompute(op: Callable, max_retries: int = 1):

@@ -30,6 +30,13 @@ under ``vmap`` over tables (the DLRM forward) a custom batching rule folds
 the mapped axis into ``T`` instead of letting Pallas loop over tables —
 the loop slices (copies) a whole table per iteration.
 
+Tables that differ in rows and pool (DLRMv2's 26 multi-hot features) come
+as one table of every table's rows and one index array a table; the grid
+is then a list of steps (:func:`ragged_steps`), each of one table's bags,
+whose pool the kernel reads from SMEM: no bag is padded to another
+table's pool.  The same body runs both grids, with the same ring, unroll
+and fetch rules.
+
 Outputs: ``R [bags, d] f32`` and ``rsum [bags] f32`` (Σ_j R[b, j]).  The
 Eq. (5) comparison against the gathered table row-sums is O(bags·pool)
 and happens in :func:`repro.core.verify_bags`.
@@ -40,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,22 +81,26 @@ def _bag_block(bags: int, pool: int, d: int) -> tuple[int, int]:
     return -(-per // 8) * 8, n_blocks
 
 
-def _kernel(idx_ref, coef_ref, table_ref, *refs, bags: int, pool: int,
-            block: int, n_blocks: int, whole: int, tail: bool, lanes: bool):
-    """One (table, bag block) step.  ``lanes``: the table is d-major
-    ([T, d, rows]) and each fetch is a ``(d, 128)`` block of rows along
-    lanes; else it is row-major and each fetch an ``(8, d)`` row group.
-    Rows from ``whole`` on come from ``tail_ref``, one group a table."""
+def _kernel(*refs, locate, n_prefetch: int, fill: int, whole: int,
+            tail: bool, lanes: bool):
+    """One grid step: the bags of one table that ``locate`` names.
+
+    ``locate(*prefetch refs)`` -> ``(t, base, n_bags, pool, out0)``: the
+    table's index in ``table_ref``, the step's first slot in the flat
+    index array, its bags, the slots a bag holds (a static int, or a
+    scalar read from SMEM where tables differ) and the output row of its
+    first bag.  ``lanes``: the table is d-major ([T, d, rows]) and each
+    fetch is a ``(d, 128)`` block of rows along lanes; else it is
+    row-major and each fetch an ``(8, d)`` row group.  Rows from ``whole``
+    on come from ``tail_ref``, one group a table."""
+    idx_ref = refs[0]
+    t, base, n_bags, pool, out0 = locate(*refs[:n_prefetch])
     if tail:
-        tail_ref, r_ref, rsum_ref, buf_ref, sem_ref = refs
+        coef_ref, table_ref, tail_ref, r_ref, rsum_ref, buf_ref, sem_ref = \
+            refs[n_prefetch:]
     else:
-        r_ref, rsum_ref, buf_ref, sem_ref = refs
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    base = (t * n_blocks + j) * (block * pool)
-    # the last block may hold fewer bags than ``block``
-    n_bags = block if bags % block == 0 else jnp.minimum(block,
-                                                         bags - j * block)
+        coef_ref, table_ref, r_ref, rsum_ref, buf_ref, sem_ref = \
+            refs[n_prefetch:]
     n_slots = n_bags * pool
     unit = buf_ref.shape[1:]
     axis = 1 if lanes else 0                 # the axis a fetch holds rows on
@@ -118,7 +130,7 @@ def _kernel(idx_ref, coef_ref, table_ref, *refs, bags: int, pool: int,
 
     # every fetch moves one group: any group's descriptor waits for it
     any_group = tail_ref.at[t] if tail else rows_at(0)
-    for s in range(min(RING - 1, block * pool)):
+    for s in range(fill):
         pl.when(s < n_slots)(functools.partial(fetch, s))
 
     pos = jax.lax.broadcasted_iota(jnp.int32, unit, axis)
@@ -147,33 +159,33 @@ def _kernel(idx_ref, coef_ref, table_ref, *refs, bags: int, pool: int,
         acc_beta = jax.lax.fori_loop(
             0, pool // UNROLL, lambda i, c: slots(i * UNROLL, UNROLL, c),
             (jnp.zeros(unit, jnp.float32), jnp.float32(0)))
-        acc, beta = slots(pool // UNROLL * UNROLL, pool % UNROLL, acc_beta)
+        if isinstance(pool, int):
+            acc_beta = slots(pool // UNROLL * UNROLL, pool % UNROLL, acc_beta)
+        else:
+            acc_beta = jax.lax.fori_loop(pool // UNROLL * UNROLL, pool, slot,
+                                         acc_beta)
+        acc, beta = acc_beta
         if lanes:
             acc = acc.T
         r = jnp.sum(acc, axis=0, keepdims=True) + beta           # [1, d]
-        r_ref[pl.ds(b, 1), :] = r
-        rsum_ref[pl.ds(b, 1), :] = jnp.broadcast_to(
+        r_ref[pl.ds(out0 + b, 1), :] = r
+        rsum_ref[pl.ds(out0 + b, 1), :] = jnp.broadcast_to(
             jnp.sum(r, axis=1, keepdims=True), (1, rsum_ref.shape[-1]))
         return carry
 
     jax.lax.fori_loop(0, n_bags, bag, 0)
 
 
-def _eb_tables_call(table_q, coef, idx, *, interpret: bool):
-    """table_q int8 [T, rows, d]; coef f32 [T, 3, bags, pool] (α, β, w per
-    slot); idx int32 [T, bags, pool] (non-negative) -> (R [T, bags, d],
-    rsum [T, bags])."""
-    n_tables, bags, pool = idx.shape
+def _pallas(table_q, coef, prefetch, *, grid, coef_block, coef_at, out_at,
+            out_tables: int, out_rows: int, out_block: int, locate,
+            fill: int, semantics, interpret: bool):
+    """The one ``pallas_call`` of every layout: ``table_q`` int8 [T, rows,
+    d] read in place, ``prefetch`` the scalar-prefetched int32 arrays (the
+    flat slot indices first), ``coef`` one SMEM block of ``coef_block``
+    (α, β, w) slots a step at ``coef_at``; outputs [out_tables, out_rows,
+    d] and [out_tables, out_rows, 128] in blocks of ``out_block`` rows at
+    ``out_at``."""
     rows, d = table_q.shape[1:]
-    block, n_blocks = _bag_block(bags, pool, d)
-    bags_p = block * n_blocks
-    if bags_p > bags:
-        pad = ((0, 0), (0, bags_p - bags), (0, 0))
-        idx = jnp.pad(idx, pad)
-        coef = jnp.pad(coef, ((0, 0),) + pad)
-    # [T, blocks, 3, block*pool]: one SMEM block of coefficients a step
-    coef = coef.reshape(n_tables, 3, n_blocks, block * pool) \
-        .transpose(0, 2, 1, 3)
     # XLA keeps an int8 table with d < 128 d-major in HBM (rows on lanes, no
     # lane padding): read it as [T, d, rows], which is the same bytes
     lanes = d < LANE_GROUP
@@ -182,10 +194,9 @@ def _eb_tables_call(table_q, coef, idx, *, interpret: bool):
     as_stored = functools.partial(jnp.swapaxes, axis1=1, axis2=2) if lanes \
         else (lambda x: x)
     whole = rows // group * group
-    args = [idx.reshape(-1), coef, as_stored(table_q)]
+    args = [*prefetch, coef, as_stored(table_q)]
     in_specs = [
-        pl.BlockSpec((None, None, 3, block * pool),
-                     lambda t, j, idx_ref: (t, j, 0, 0),
+        pl.BlockSpec((None, None, 3, coef_block), coef_at,
                      memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -194,29 +205,126 @@ def _eb_tables_call(table_q, coef, idx, *, interpret: bool):
         args.append(as_stored(jnp.pad(
             table_q[:, whole:], ((0, 0), (0, whole + group - rows), (0, 0)))))
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    out = lambda t, j, idx_ref: (t, j, 0)                  # noqa: E731
-    r, rsum = pl.pallas_call(
-        functools.partial(_kernel, bags=bags, pool=pool, block=block,
-                          n_blocks=n_blocks, whole=whole, tail=whole < rows,
+    return pl.pallas_call(
+        functools.partial(_kernel, locate=locate, n_prefetch=len(prefetch),
+                          fill=fill, whole=whole, tail=whole < rows,
                           lanes=lanes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_tables, n_blocks),
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((None, block, d), out),
-                       pl.BlockSpec((None, block, 128), out)],
+            out_specs=[pl.BlockSpec((None, out_block, d), out_at),
+                       pl.BlockSpec((None, out_block, 128), out_at)],
             scratch_shapes=[pltpu.VMEM((RING,) + unit, jnp.int8),
                             pltpu.SemaphoreType.DMA((RING,))],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((n_tables, bags_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((n_tables, bags_p, 128), jnp.float32),
+            jax.ShapeDtypeStruct((out_tables, out_rows, d), jnp.float32),
+            jax.ShapeDtypeStruct((out_tables, out_rows, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(*args)
+
+
+def _eb_tables_call(table_q, coef, idx, *, interpret: bool):
+    """table_q int8 [T, rows, d]; coef f32 [T, 3, bags, pool] (α, β, w per
+    slot); idx int32 [T, bags, pool] (non-negative) -> (R [T, bags, d],
+    rsum [T, bags])."""
+    n_tables, bags, pool = idx.shape
+    block, n_blocks = _bag_block(bags, pool, table_q.shape[-1])
+    bags_p = block * n_blocks
+    if bags_p > bags:
+        pad = ((0, 0), (0, bags_p - bags), (0, 0))
+        idx = jnp.pad(idx, pad)
+        coef = jnp.pad(coef, ((0, 0),) + pad)
+    # [T, blocks, 3, block*pool]: one SMEM block of coefficients a step
+    coef = coef.reshape(n_tables, 3, n_blocks, block * pool) \
+        .transpose(0, 2, 1, 3)
+
+    def locate(idx_ref):
+        t, j = pl.program_id(0), pl.program_id(1)
+        # the last block may hold fewer bags than ``block``
+        n_bags = block if bags % block == 0 else jnp.minimum(
+            block, bags - j * block)
+        return t, (t * n_blocks + j) * (block * pool), n_bags, pool, 0
+
+    at = lambda t, j, idx_ref: (t, j, 0)                   # noqa: E731
+    r, rsum = _pallas(
+        table_q, coef, [idx.reshape(-1)], grid=(n_tables, n_blocks),
+        coef_block=block * pool,
+        coef_at=lambda t, j, idx_ref: (t, j, 0, 0), out_at=at,
+        out_tables=n_tables, out_rows=bags_p, out_block=block, locate=locate,
+        fill=min(RING - 1, block * pool), semantics=("parallel", "parallel"),
+        interpret=interpret)
     return r[:, :bags], rsum[:, :bags, 0]
+
+
+def ragged_steps(bags: int, pools) -> np.ndarray:
+    """The grid of a call over tables that differ in pool: one row a step,
+    ``(table, first bag, bags, pool, first slot)``, each table's steps
+    together and in order, each step about ``STEP_SLOTS`` slots (one bag
+    at least)."""
+    steps, base = [], 0
+    for t, pool in enumerate(pools):
+        n = -(-bags // max(1, min(bags, STEP_SLOTS // pool)))
+        per = -(-bags // n)
+        for b0 in range(0, bags, per):
+            nb = min(per, bags - b0)
+            steps.append((t, b0, nb, pool, base))
+            base += nb * pool
+    return np.asarray(steps, np.int32)
+
+
+def grid_slots(indices, d: int) -> int:
+    """Slots (bag positions, −1 pads included) that a call on ``indices``
+    of a d-wide table runs: Σ over its grid's steps of bags × pool, from
+    the grid the call builds (:func:`ragged_steps`, or :func:`_bag_block`
+    whose last block runs only the bags it holds)."""
+    if isinstance(indices, (tuple, list)):
+        steps = ragged_steps(indices[0].shape[0],
+                             [i.shape[1] for i in indices]).astype(np.int64)
+        return int(np.sum(steps[:, 2] * steps[:, 3]))
+    bags, pool = indices.shape
+    block, n_blocks = _bag_block(bags, pool, d)
+    return sum(min(block, bags - j * block) for j in range(n_blocks)) * pool
+
+
+def _eb_ragged_call(table_q, coef, idx, pools, *, interpret: bool):
+    """Tables of any rows and pools in one call: table_q int8 [R, d] (every
+    table's rows, one after the other); idx int32 [S] (non-negative rows
+    of ``table_q``) and coef f32 [3, S] (α, β, w), table after table, bag
+    after bag, ``pools[t]`` slots a bag -> (R [T, bags, d], rsum [T,
+    bags]).  A step reads its bags' pool from SMEM, and each table's
+    outputs stay in VMEM while its steps run."""
+    bags = idx.shape[0] // sum(pools)
+    steps = ragged_steps(bags, pools)
+    width = int(np.max(steps[:, 2] * steps[:, 3]))
+    # [steps, 3, width]: each step's coefficients, zero-padded to one block
+    blocks, at = [], 0
+    for t, pool in enumerate(pools):
+        mine = steps[steps[:, 0] == t]
+        n, per = len(mine), int(mine[0, 2])
+        c = coef[:, at:at + bags * pool]
+        c = jnp.pad(c, ((0, 0), (0, (n * per - bags) * pool)))
+        c = c.reshape(3, n, per * pool).transpose(1, 0, 2)
+        blocks.append(jnp.pad(c, ((0, 0), (0, 0), (0, width - per * pool))))
+        at += bags * pool
+
+    def locate(idx_ref, step_ref):
+        s = pl.program_id(0) * 5
+        return 0, step_ref[s + 4], step_ref[s + 2], step_ref[s + 3], \
+            step_ref[s + 1]
+
+    r, rsum = _pallas(
+        table_q[None], jnp.concatenate(blocks)[:, None],
+        [idx, jnp.asarray(steps.reshape(-1))], grid=(len(steps),),
+        coef_block=width, coef_at=lambda s, idx_ref, step_ref: (s, 0, 0, 0),
+        out_at=lambda s, idx_ref, step_ref: (step_ref[s * 5], 0, 0),
+        out_tables=len(pools), out_rows=bags, out_block=bags, locate=locate,
+        fill=min(RING - 1, width), semantics=("arbitrary",),
+        interpret=interpret)
+    return r, rsum[..., 0]
 
 
 def _fold_tables(interpret: bool):
@@ -262,19 +370,37 @@ _FOLDED = {False: _fold_tables(False), True: _fold_tables(True)}
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def abft_eb_pallas(table_q: jax.Array, alphas: jax.Array, betas: jax.Array,
-                   indices: jax.Array, weights: jax.Array | None = None, *,
-                   interpret: bool = False):
+                   indices, weights=None, *, interpret: bool = False):
     """Gather-and-sum with fused RSum. Returns ``(R [bags,d], rsum [bags])``.
 
     table_q int8 [rows, d]; alphas/betas f32 [rows]; indices int32
     [bags, pool] (−1 padded); weights f32 [bags, pool] or None.
+
+    ``indices`` may also be a tuple with one [bags, pool_t] array a table
+    (``weights`` a like tuple, or None): ``table_q`` then holds every
+    table's rows one after the other, the indices are rows of it, and
+    -> ``(R [T, bags, d], rsum [T, bags])`` from one kernel call that
+    walks each table's own pool (none is padded to another's).
     """
-    valid = indices >= 0
-    safe_idx = jnp.where(valid, indices, 0).astype(jnp.int32)
+    ragged = isinstance(indices, (tuple, list))
+    if ragged:
+        # every table's slots at once, as verify_bags flattens them, so
+        # that XLA gathers each of α and β once for both
+        flat = jnp.concatenate([i.reshape(-1) for i in indices])
+        weights = None if weights is None else jnp.concatenate(
+            [w.reshape(-1) for w in weights])
+    else:
+        flat = indices
+    valid = flat >= 0
+    safe_idx = jnp.where(valid, flat, 0).astype(jnp.int32)
     w = jnp.ones_like(alphas[safe_idx]) if weights is None else weights
     w = jnp.where(valid, w, 0.0)
-    # [3, bags, pool]: per-slot (alpha, beta, weight) — gathered by XLA
+    # [3, ...]: per-slot (alpha, beta, weight) — gathered by XLA
     # (O(bags*pool) — negligible vs the O(bags*pool*d) row traffic).
     coef = jnp.stack([alphas[safe_idx], betas[safe_idx], w])
-    r, rsum = _FOLDED[interpret](table_q[None], coef[None], safe_idx[None])
-    return r[0], rsum[0]
+    if not ragged:
+        r, rsum = _FOLDED[interpret](table_q[None], coef[None],
+                                     safe_idx[None])
+        return r[0], rsum[0]
+    return _eb_ragged_call(table_q, coef, safe_idx,
+                           [i.shape[1] for i in indices], interpret=interpret)

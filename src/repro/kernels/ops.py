@@ -92,6 +92,17 @@ def abft_embedding_bag(table_q, alphas, betas, indices, rowsums,
                          weights, rel_bound)
 
 
+def eb_slots(indices, d: int, *, use_pallas: Optional[bool] = None,
+             interpret: bool = False) -> int:
+    """Slots (bag positions, −1 pads included) that
+    :func:`abft_embedding_bag` runs on ``indices``: the Pallas kernel's
+    grid, or every bag position of the XLA gather."""
+    if _use_pallas(use_pallas, interpret):
+        from repro.kernels.abft_embeddingbag import grid_slots
+        return grid_slots(indices, d)
+    return sum(i.size for i in jax.tree.leaves(indices))
+
+
 def quantize_rows(x: jax.Array, *, use_pallas: Optional[bool] = None,
                   interpret: bool = False):
     """Per-row signed-int8 dynamic quantization. -> (q, alpha, beta)."""

@@ -211,7 +211,12 @@ class FloatGemmOp:
 
 class EmbeddingBagOp:
     """Quantized EB: encoded = (table_q, alphas, betas, rowsums);
-    inputs = (indices [bags, pool] (−1 padded), optional weights)."""
+    inputs = (indices [bags, pool] (−1 padded), optional weights).
+
+    ``indices`` may be a tuple with one [bags, pool_t] array a table, of
+    rows of a table that holds every table's rows: one call then looks
+    up every table and counts one check a table.  The report's
+    ``eb_slots`` counts the slots the lookup ran (the kernel's grid)."""
     name = "embedding_bag"
     schemes = ("xla", "pallas")
     supports_correct = False
@@ -239,7 +244,11 @@ class EmbeddingBagOp:
         out = kops.abft_embedding_bag(table_q, alphas, betas, indices,
                                       rowsums, weights, rel_bound=rel,
                                       use_pallas=use_pallas)
-        return out.r, Check(out.err_count, out.err_bags)
+        aux = {"eb_slots": kops.eb_slots(indices, table_q.shape[-1],
+                                         use_pallas=use_pallas)}
+        if isinstance(indices, (tuple, list)):
+            aux["n_checks"] = len(indices)
+        return out.r, Check(out.err_count, out.err_bags, aux)
 
     def unprotected(self, encoded, indices, weights=None):
         table_q, alphas, betas, _ = encoded

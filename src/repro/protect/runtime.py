@@ -89,10 +89,11 @@ def protected_call(op: str, encoded, *inputs, ctx=None,
     # adapters whose one call covers a variable amount of verified state
     # (e.g. pages touched by a paged KV read) report it via Check.aux so
     # the checks counter prices verification work, not call count
-    n_checks = 1
-    if isinstance(check.aux, dict) and "n_checks" in check.aux:
-        n_checks = check.aux["n_checks"]
-    return out, op_report(op, check.err_count, checks=n_checks)
+    # and an EmbeddingBag the slots it ran
+    aux = check.aux if isinstance(check.aux, dict) else {}
+    return out, op_report(op, check.err_count,
+                          checks=aux.get("n_checks", 1),
+                          eb_slots=aux.get("eb_slots"))
 
 
 def observe_metrics(metrics, *, source: str, step: int = 0,

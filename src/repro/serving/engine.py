@@ -196,6 +196,9 @@ class ServingEngine:
         self._fetches = 0
         #: tracer time at which the current step was dispatched
         self._dispatch_s = 0.0
+        #: a DLRM step's ``eb_slots`` by (lane, bag shape): fixed by the
+        #: shapes, so read to the host once (at warmup where it can be)
+        self._eb_slots: Dict[tuple, int] = {}
 
         #: PagingConfig | None — paged, prefix-shared, per-page-checksummed
         #: KV mode.  Prompts round up to page-multiple buckets, slots hold
@@ -424,9 +427,12 @@ class ServingEngine:
                                      jnp.int32)
                 else:
                     dense = jnp.zeros((1, ex.n_dense), jnp.float32)
-                    bags = jnp.zeros((ex.n_tables, 1, 1), jnp.int32)
-                jax.block_until_ready(
+                    bags = jnp.zeros((1, sum(ex.pools)) if ex.ragged
+                                     else (ex.n_tables, 1, 1), jnp.int32)
+                _, metrics = jax.block_until_ready(
                     lane.forward_fn(self.params, dense, bags))
+                self._eb_slots[(lane.key, bags.shape)] = int(
+                    metrics["abft/eb_slots"])
                 continue
             if lane.pager is not None:
                 self._warmup_paged(lane, dummy)
@@ -1086,6 +1092,11 @@ class ServingEngine:
         else:
             (host_logits,), counters = self._readback(metrics, logits)
             host_logits = host_logits.tolist()
+            key = (lane.key, bags.shape)
+            if key not in self._eb_slots:
+                self._eb_slots[key] = self._fetch(int,
+                                                  metrics["abft/eb_slots"])
+            counters[0]["eb_slots"] = self._eb_slots[key]
         slot_like.first_token_s = None if aborted else self.clock_s
         self._record_slot(slot_like, telemetry, aborted=aborted,
                           logits=host_logits)

@@ -375,6 +375,90 @@ def test_eb_kernel_padding_and_weights(rng, rows, d, bags, pool):
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("rows,pools,bags,d", [
+    # a 3-row table, rows % 8 != 0 (the last group from the tail), a pool
+    # past the DMA ring, one past a step's slots
+    ((3, 203, 1000, 17), (1, 2 * RING + 3, 7, 1100), 21, 128),
+    # d = 64 tables, read d-major; one-hot bags in one step
+    ((130, 5), (1, 9), 700, 64),
+], ids=["dlrmv2-like", "d64"])
+def test_eb_kernel_ragged_matches_gather_sum(rng, rows, pools, bags, d):
+    """Tables of unequal rows and pools in one kernel call, against an XLA
+    gather-sum per table: −1 slots add nothing, weights scale each row,
+    each table's last row is read; and the lookup counts as many
+    ``eb_slots`` as it has bag positions."""
+    from repro.layers.common import Ctx
+    from repro.layers.embedding import embedding_bag_fwd
+    from repro.protect import ProtectionPlan
+    offs = np.cumsum((0,) + rows)
+    t, al, be = _eb_inputs(rng, (), int(offs[-1]), d)
+    idx, w = [], []
+    for i, (n, p) in enumerate(zip(rows, pools)):
+        x = rng.integers(0, n, size=(bags, p))
+        x[rng.uniform(size=x.shape) < 0.2] = -1
+        x[-1, -1] = n - 1
+        idx.append(jnp.asarray(np.where(x >= 0, x + offs[i], -1), jnp.int32))
+        w.append(jnp.asarray(rng.uniform(-2.0, 2.0, size=x.shape),
+                             jnp.float32))
+    r, rsum = abft_eb_pallas(t, al, be, tuple(idx), tuple(w), interpret=True)
+    assert r.shape == (len(rows), bags, d) and rsum.shape == (len(rows), bags)
+    for i in range(len(rows)):
+        r_ref, rsum_ref = kref.abft_eb_ref(t, al, be, idx[i], w[i])
+        np.testing.assert_allclose(np.asarray(r[i]), np.asarray(r_ref),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(rsum[i]), np.asarray(rsum_ref),
+                                   rtol=1e-4, atol=1e-3)
+    from repro.core.abft_embedding import table_rowsums
+    p = {"table": t, "alphas": al, "betas": be, "rowsums": table_rowsums(t)}
+    ctx = Ctx(plan=ProtectionPlan.parse("*:policy=log:scheme=pallas"))
+    got, rep = embedding_bag_fwd(p, tuple(idx), ctx, tuple(w))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(r.astype(got.dtype), np.float32))
+    assert int(rep.eb_slots) == bags * sum(pools)
+    assert int(rep.checks["embedding_bag"]) == len(rows)
+    assert int(rep.errors["embedding_bag"]) == 0
+
+
+@pytest.mark.parametrize("bags,pools", [(512, (3, 1, 100, 27, 1100)),
+                                        (7, (1, 2))])
+def test_ragged_steps_cover_every_bag_once(bags, pools):
+    """The ragged grid: each table's bags in order, in steps of at most
+    STEP_SLOTS slots (one bag where its pool alone is more), the slots of
+    every step one after the other."""
+    from repro.kernels.abft_embeddingbag import STEP_SLOTS, ragged_steps
+    steps = ragged_steps(bags, pools)
+    slot = 0
+    for t, pool in enumerate(pools):
+        mine = steps[steps[:, 0] == t]
+        assert (mine[:, 3] == pool).all()
+        assert list(mine[:, 1]) == list(np.cumsum([0] + list(mine[:-1, 2])))
+        assert mine[:, 2].sum() == bags
+        assert (mine[:, 2] * pool <= max(STEP_SLOTS, pool)).all()
+        for _, _, nb, _, base in mine:
+            assert base == slot
+            slot += nb * pool
+    assert slot == bags * sum(pools)
+
+
+def test_grid_slots_count_what_the_grid_runs(monkeypatch):
+    """``eb_slots`` is read off the kernel's grid: every bag position once
+    (the last bag block runs only the bags it holds), and a grid that
+    padded each bag to the largest pool would count its padding."""
+    from repro.kernels import abft_embeddingbag as eb
+    from repro.kernels.ops import eb_slots
+    pools = (3, 1, 100, 27)
+    ragged = tuple(jnp.zeros((512, p), jnp.int32) for p in pools)
+    assert eb.grid_slots(ragged, 128) == 512 * 131
+    assert eb_slots(ragged, 128, use_pallas=False) == 512 * 131
+    for bags, pool, d in ((2500, 1, 128), (19, 45, 64), (21, 100, 128)):
+        assert eb.grid_slots(jnp.zeros((bags, pool), jnp.int32), d) \
+            == bags * pool
+    steps = eb.ragged_steps
+    monkeypatch.setattr(eb, "ragged_steps", lambda bags, p: steps(
+        bags, [max(p)] * len(p)))
+    assert eb.grid_slots(ragged, 128) == 512 * 100 * len(pools)
+
+
 def test_eb_ops_end_to_end_detection(rng):
     from repro.core.abft_embedding import table_rowsums
     t = jnp.asarray(rng.integers(-128, 128, size=(128, 64)), jnp.int8)

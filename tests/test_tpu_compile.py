@@ -10,6 +10,7 @@ The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import functools
 import math
 
@@ -18,8 +19,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.dlrm import EXTRAS
+from repro.configs.dlrm import DCNV2, EXTRAS
 from repro.core import LANE
+
+#: the DLRMv2 benchmark cell's chip: rows 0..20M-1 of each 40M-row table
+#: (two chips split those tables by rows), every other table whole
+DCNV2_CHIP = dataclasses.replace(
+    DCNV2, table_rows=tuple(min(r, 20_000_000) for r in DCNV2.rows))
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
 
@@ -138,3 +144,65 @@ def test_dlrm_pallas_lane_compiles_at_paper_geometry(one_chip, monkeypatch):
     n_kernels = compiled.as_text().count(MOSAIC)
     assert n_kernels > len(ex.bottom_mlp) + len(ex.top_mlp)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_abft_eb_compiles_multi_hot(one_chip):
+    """DLRMv2's 26 multi-hot features in one kernel call at the benchmark
+    cell's shapes: tables of 3 to 20M rows held one after the other (13.3
+    GB of int8), batch 512, each table's own pool (214 ids a sample).  The
+    ids fit SMEM, the table is read in place, and no temporary nears a
+    table's size."""
+    from repro.kernels.abft_embeddingbag import abft_eb_pallas
+    from repro.layers.embedding import table_offsets
+    ex = DCNV2_CHIP
+    rows = table_offsets(ex.rows)[-1]
+    args = (_shape(one_chip, (rows, ex.emb_dim), jnp.int8),
+            _shape(one_chip, (rows,), jnp.float32),
+            _shape(one_chip, (rows,), jnp.float32),
+            tuple(_shape(one_chip, (ex.batch, p), jnp.int32)
+                  for p in ex.pools))
+    mem = _compile(functools.partial(abft_eb_pallas, weights=None),
+                   *args).memory_analysis()
+    assert mem.argument_size_in_bytes < rows * (ex.emb_dim + 8) * 1.01
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_dlrm_dcnv2_lane_compiles_at_benchmark_geometry(one_chip,
+                                                        monkeypatch):
+    """The served DLRMv2 forward under the default plan at the benchmark
+    cell's shapes: the multi-hot EmbeddingBag and all 14 GEMMs (3 bottom,
+    6 cross, 5 top) run as Mosaic kernels, and the program needs little
+    beyond its 14.6 GB of arguments."""
+    from repro.kernels import ops
+    from repro.models.dlrm import dlrm_forward, init_dlrm
+    from repro.protect import default_plan, protect
+    from repro.sharding import values_of
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    ex = DCNV2_CHIP
+    params = jax.tree.map(
+        lambda s: _shape(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda: values_of(init_dlrm(jax.random.key(0), ex))))
+    fwd = protect(functools.partial(dlrm_forward, ex=ex), default_plan(),
+                  compute_dtype=jnp.bfloat16)
+    compiled = _compile(
+        lambda p, d, b: (lambda o: (o[0], o[1].as_metrics()))(fwd(p, d, b)),
+        params, _shape(one_chip, (ex.batch, ex.n_dense), jnp.float32),
+        _shape(one_chip, (ex.batch, sum(ex.pools)), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("abft_qgemm_pallas") and text.count("abft_eb_pallas")
+    mem = compiled.memory_analysis()
+    assert 14.5e9 < mem.argument_size_in_bytes < 14.7e9
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_dlrm_dcnv2_init_fits_one_chip(one_chip):
+    """Drawing the cell's tables (one 1M-row chunk at a time, written in
+    place) needs little beyond the 14.6 GB it returns."""
+    from repro.models.dlrm import init_dlrm
+    from repro.sharding import values_of
+    key = _shape(one_chip, (), jax.random.key(0).dtype)
+    mem = jax.jit(lambda k: values_of(init_dlrm(k, DCNV2_CHIP))).lower(
+        key).compile().memory_analysis()
+    assert 14.5e9 < mem.output_size_in_bytes < 14.7e9
+    assert mem.temp_size_in_bytes < 2e9
