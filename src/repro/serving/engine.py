@@ -31,8 +31,11 @@ trace shows what the host did around each of the device's ops:
 arrays sent to the device), ``serving/dispatch`` (launching a jitted
 step), ``serving/wait`` (blocked until it finishes) and
 ``serving/readback`` (its outputs and FaultReport counters read back).
-Each blocking device-to-host read is counted on the step's
-:class:`StepEvent` (``host_fetches``).
+Each jitted step also returns its FaultReport counters packed into one
+int32 vector, so a step's outputs and counters come back in one
+``jax.device_get``.  Each blocking device-to-host read is counted on the
+step's :class:`StepEvent` (``host_fetches``); a batched transfer counts
+as one, so a DLRM step reads 1.
 
 Fault injection is first-class: a :class:`FaultInjection` flips a bit in
 a plan-path-addressed weight leaf right before a chosen step and — unless
@@ -92,14 +95,38 @@ def tenant_weights(tenants: Sequence[TenantSpec]) -> Dict[str, float]:
     return {t.name: t.weight for t in tenants}
 
 
-def _counters_of(metrics: dict, fetch) -> tuple:
-    """(per-op int counters, total residual errors) from step metrics,
-    each read to the host as ``fetch(int, value)``."""
+def _counter_keys(metrics: dict) -> tuple:
+    """The FaultReport counters a step's ``metrics`` hold, in a fixed
+    order: each op kind's checks and errors, then retries and
+    corrections.  Op kinds only ever append, so the keys a step was
+    traced with come out in the same order when its metrics are read."""
+    from repro.core.policy import op_kinds
+    keys = [f"abft/{k}_{c}" for k in op_kinds() for c in ("checks",
+                                                          "errors")]
+    keys += ["abft/retries", "abft/corrections"]
+    return tuple(k for k in keys if k in metrics)
+
+
+def _report_out(rep) -> tuple:
+    """(traced) A step's FaultReport as (metrics, packed counters): the
+    metrics' :func:`_counter_keys` values in one int32 vector, read back
+    with the step's outputs in one transfer."""
+    import jax.numpy as jnp
+    metrics = rep.as_metrics()
+    return metrics, jnp.stack([jnp.asarray(metrics[k], jnp.int32)
+                               for k in _counter_keys(metrics)])
+
+
+def _counters_of(metrics: dict) -> tuple:
+    """(per-op int counters, total residual errors) from step metrics; a
+    counter the metrics lack reads 0.  On device values each ``int`` is a
+    blocking read: the engine passes the host values of one packed
+    vector (:meth:`ServingEngine._readback`)."""
     from repro.core.policy import op_kinds
 
     def read(key):
         v = metrics.get(key)
-        return 0 if v is None else fetch(int, v)
+        return 0 if v is None else int(v)
 
     out: Dict[str, int] = {}
     errors = 0
@@ -283,7 +310,7 @@ class ServingEngine:
             @jax.jit
             def forward(params, dense, bags):
                 logit, rep = fwd_p(params, dense, bags)
-                return logit, rep.as_metrics()
+                return logit, *_report_out(rep)
 
             lane.forward_fn = forward
             return
@@ -299,7 +326,7 @@ class ServingEngine:
             (logits, new_cache), rep = decode_p(params, cache, tokens, pos)
             tok = jnp.argmax(logits[..., :cfg.vocab],
                              axis=-1).astype(jnp.int32)
-            return tok, new_cache, rep.as_metrics()
+            return tok, new_cache, *_report_out(rep)
 
         lane.decode_fn = decode
 
@@ -314,7 +341,7 @@ class ServingEngine:
                                                  cache_len=cache_len)
                 tok = jnp.argmax(logits[..., :cfg.vocab],
                                  axis=-1).astype(jnp.int32)
-                return tok, cache, rep.as_metrics()
+                return tok, cache, *_report_out(rep)
 
             @jax.jit
             def insert_pages(cache, one, page_ids, table):
@@ -351,7 +378,7 @@ class ServingEngine:
                                              cache_len=self.cache_len)
             tok = jnp.argmax(logits[..., :cfg.vocab],
                              axis=-1).astype(jnp.int32)
-            return tok, cache, rep.as_metrics()
+            return tok, cache, *_report_out(rep)
 
         @jax.jit
         def insert(full, one, slot):
@@ -429,7 +456,7 @@ class ServingEngine:
                     dense = jnp.zeros((1, ex.n_dense), jnp.float32)
                     bags = jnp.zeros((1, sum(ex.pools)) if ex.ragged
                                      else (ex.n_tables, 1, 1), jnp.int32)
-                _, metrics = jax.block_until_ready(
+                _, metrics, _ = jax.block_until_ready(
                     lane.forward_fn(self.params, dense, bags))
                 self._eb_slots[(lane.key, bags.shape)] = int(
                     metrics["abft/eb_slots"])
@@ -437,8 +464,8 @@ class ServingEngine:
             if lane.pager is not None:
                 self._warmup_paged(lane, dummy)
                 continue
-            tok, cache1, _ = lane.prefill_fn(self.params,
-                                             self._chat_batch(dummy))
+            tok, cache1, *_ = lane.prefill_fn(self.params,
+                                              self._chat_batch(dummy))
             full = self._widened_cache(cache1, lane.n_slots)
             full = lane.insert_fn(full, cache1, 0)
             toks = jnp.zeros((lane.n_slots,), jnp.int32)
@@ -461,7 +488,7 @@ class ServingEngine:
         nc = bucket // p
         batch = {"tokens": jnp.asarray(
             self._chat_tokens(dummy, bucket)[None, :], jnp.int32)}
-        tok, cache1, _ = lane.prefill_fn(self.params, batch, bucket)
+        tok, cache1, *_ = lane.prefill_fn(self.params, batch, bucket)
         if lane.cache is None:
             self._init_paged_cache(lane, cache1)
         tb = np.full((lane.n_slots, lane.pager.max_pages), -1, np.int32)
@@ -746,19 +773,24 @@ class ServingEngine:
         return out, dt
 
     def _fetch(self, read, x):
-        """``read(x)``: one blocking device-to-host read (``int`` of a
-        scalar, ``np.asarray`` of an array), counted on the next step
+        """``read(x)``: one blocking device-to-host read (``np.asarray``
+        of an array, or ``jax.device_get`` of several, which starts every
+        transfer before it waits on any), counted on the next step
         event's ``host_fetches``."""
         self._fetches += 1
         return read(x)
 
-    def _readback(self, metrics, *outs) -> tuple:
-        """A step's blocking reads under one span: each of ``outs``, then
-        the FaultReport counters -> (host arrays, (counters, errors))."""
+    def _readback(self, metrics, packed, *outs) -> tuple:
+        """A step's one blocking read, under one span: ``outs`` and its
+        :func:`_report_out` vector ``packed`` in one ``device_get`` ->
+        (host arrays, (counters, errors)), the counters as
+        :func:`_counters_of` reads them from ``metrics``."""
+        import jax
         with span("serving/readback", self._tracer):
-            host = tuple(self._fetch(np.asarray, x) for x in outs)
-            counters = _counters_of(metrics, self._fetch)
-        return host, counters
+            *host, vec = self._fetch(jax.device_get, (*outs, packed))
+            counters = _counters_of(
+                dict(zip(_counter_keys(metrics), vec.tolist())))
+        return tuple(host), counters
 
     def _record_slot(self, slot: Slot, telemetry: Telemetry,
                      aborted: bool = False, logits=None):
@@ -777,12 +809,10 @@ class ServingEngine:
                     telemetry: Telemetry, injected: bool = False,
                     errors_override: Optional[int] = None,
                     slot_rids: tuple = (), counters=None):
-        """Record one step.  ``counters`` is the (counters, errors) pair a
-        caller already read back; otherwise ``metrics`` are read here."""
-        if counters is None:
-            counters = (({}, 0) if metrics is None
-                        else self._readback(metrics)[1])
-        counters, errors = counters
+        """Record one step.  ``counters`` is the (counters, errors) pair
+        the caller read back (:meth:`_readback`); None for a step that
+        returned none (an abort)."""
+        counters, errors = ({}, 0) if counters is None else counters
         if errors_override is not None:
             errors = errors_override
         fetches, self._fetches = self._fetches, 0
@@ -842,7 +872,7 @@ class ServingEngine:
             return
         req = slot.request
         try:
-            (tok, cache1, metrics), dt = self._timed(
+            (tok, cache1, metrics, packed), dt = self._timed(
                 lane.prefill_fn, self.params, self._chat_batch(req))
         except Exception as e:          # noqa: BLE001 - abort policy only
             if not is_fault_abort(e):
@@ -865,7 +895,7 @@ class ServingEngine:
         slot.pos = self._prefill_pos()
         slot.generated = 1
         slot.first_token_s = self.clock_s
-        (tok_host,), counters = self._readback(metrics, tok)
+        (tok_host,), counters = self._readback(metrics, packed, tok)
         slot.token_ids = [int(tok_host[0])]
         slot.prefill_tokens = self.max_prompt   # full fixed-slot bucket
         self._step_event("prefill", lane, dt, metrics, telemetry,
@@ -892,7 +922,7 @@ class ServingEngine:
             return
         batch = {"tokens": jnp.asarray(tokens[None, :], jnp.int32)}
         try:
-            (tok, cache1, metrics), dt = self._timed(
+            (tok, cache1, metrics, packed), dt = self._timed(
                 lane.prefill_fn, self.params, batch, bucket)
         except Exception as e:          # noqa: BLE001 - abort policy only
             if not is_fault_abort(e):
@@ -919,7 +949,7 @@ class ServingEngine:
         slot.pos = bucket
         slot.generated = 1
         slot.first_token_s = self.clock_s
-        (tok_host,), counters = self._readback(metrics, tok)
+        (tok_host,), counters = self._readback(metrics, packed, tok)
         slot.token_ids = [int(tok_host[0])]
         slot.bucket = bucket
         slot.prefill_tokens, slot.shared_prefix_tokens = plan.tokens(p)
@@ -943,7 +973,7 @@ class ServingEngine:
         resident = tuple(s.request.rid
                          for s in lane.batcher.active_slots())
         try:
-            (tok, cache, metrics), dt = self._timed(
+            (tok, cache, metrics, packed), dt = self._timed(
                 lane.decode_fn, self.params, lane.cache, lane.tokens,
                 lane.pos)
         except Exception as e:          # noqa: BLE001 - abort policy only
@@ -956,7 +986,7 @@ class ServingEngine:
         lane.cache = cache
         lane.tokens = tok
         lane.pos = lane.pos + 1
-        (tok_host,), counters = self._readback(metrics, tok)
+        (tok_host,), counters = self._readback(metrics, packed, tok)
         for slot in lane.batcher.active_slots():
             slot.generated += 1
             slot.pos += 1
@@ -966,8 +996,7 @@ class ServingEngine:
                                   counters=counters)
         if lane.pager is not None and errors > 0:
             policy = lane.plan.resolve("kv_cache_paged", "attn").policy
-            paged = metrics.get("abft/kv_cache_paged_errors")
-            if paged is not None and self._fetch(int, paged) > 0 \
+            if counters[0].get("kv_cache_paged_errors", 0) > 0 \
                     and policy != "log":
                 self._paged_repair(lane, telemetry, policy)
         for slot in lane.batcher.retire_finished():
@@ -1053,7 +1082,7 @@ class ServingEngine:
             return False
         batch = {"tokens": jnp.asarray(tokens[None, :], jnp.int32)}
         try:
-            (_, cache1, metrics), dt = self._timed(
+            (_, cache1, metrics, packed), dt = self._timed(
                 lane.prefill_fn, self.params, batch, bucket)
         except Exception as e:          # noqa: BLE001 - abort policy only
             if not is_fault_abort(e):
@@ -1066,7 +1095,8 @@ class ServingEngine:
         self._paging_event("rebuild", lane, dur_s=dt, slot=slot.index,
                            rid=req.rid, pages=len(plan.page_ids))
         self._step_event("rebuild", lane, dt, metrics, telemetry,
-                         slot_rids=(req.rid,))
+                         slot_rids=(req.rid,),
+                         counters=self._readback(metrics, packed)[1])
         return True
 
     def _do_dlrm(self, lane: _Lane, slot_like: Slot, telemetry: Telemetry,
@@ -1082,7 +1112,7 @@ class ServingEngine:
         aborted = False
         metrics, host_logits, counters, dt = None, None, None, 0.0
         try:
-            (logits, metrics), dt = self._timed(
+            (logits, metrics, packed), dt = self._timed(
                 lane.forward_fn, self.params, dense, bags)
         except Exception as e:          # noqa: BLE001 - abort policy only
             if not is_fault_abort(e):
@@ -1090,12 +1120,15 @@ class ServingEngine:
             self.clock_s += 1e-6
             aborted = True
         else:
-            (host_logits,), counters = self._readback(metrics, logits)
-            host_logits = host_logits.tolist()
             key = (lane.key, bags.shape)
-            if key not in self._eb_slots:
-                self._eb_slots[key] = self._fetch(int,
-                                                  metrics["abft/eb_slots"])
+            # a bag shape not seen at warm-up reads its slots in the
+            # same transfer
+            new = () if key in self._eb_slots else (metrics["abft/eb_slots"],)
+            (host_logits, *slots), counters = self._readback(
+                metrics, packed, logits, *new)
+            host_logits = host_logits.tolist()
+            if slots:
+                self._eb_slots[key] = int(slots[0])
             counters[0]["eb_slots"] = self._eb_slots[key]
         slot_like.first_token_s = None if aborted else self.clock_s
         self._record_slot(slot_like, telemetry, aborted=aborted,

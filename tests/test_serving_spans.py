@@ -12,11 +12,12 @@ import jax
 import numpy as np
 import pytest
 
+from repro.configs import reduce_cfg
 from repro.configs.dlrm import DlrmExtras
 from repro.configs.registry import get_arch
 from repro.core.policy import op_kinds
 from repro.obs import Observability, Span, Tracer, span
-from repro.serving import ServingEngine, TenantSpec
+from repro.serving import FaultInjection, ServingEngine, TenantSpec
 from repro.serving import engine as engine_mod
 from repro.serving.telemetry import TELEMETRY_SCHEMA_VERSION
 from repro.serving.workload import Request
@@ -33,9 +34,15 @@ STEP_SPANS = ("serving/upload", "serving/dispatch", "serving/wait",
 
 
 def dlrm_fetches() -> int:
-    """What a DLRM step reads back: its logits, each registered op kind's
-    checks and errors, retries and corrections."""
-    return 1 + 2 * len(op_kinds()) + 2
+    """What a DLRM step reads back: one transfer of its logits and its
+    packed FaultReport counters."""
+    return 1
+
+
+def n_counters() -> int:
+    """The packed counters: each registered op kind's checks and errors,
+    retries and corrections."""
+    return 2 * len(op_kinds()) + 2
 
 
 def request(i: int) -> Request:
@@ -97,7 +104,8 @@ def test_span_and_tracer_spans_are_profiler_host_events(tmp_path):
 
 def test_a_dlrm_step_counts_each_device_to_host_read(engine, monkeypatch):
     """The count on the step event equals the reads seen by wrapping
-    every conversion of a device array to the host."""
+    every conversion of a device array to the host, and each
+    ``jax.device_get`` (one read of all its arrays)."""
     reads = []
 
     class CountingNumpy:
@@ -120,13 +128,21 @@ def test_a_dlrm_step_counts_each_device_to_host_read(engine, monkeypatch):
             reads.append(self.shape)
             return _orig(self, *a, **kw)
         monkeypatch.setattr(array_type, name, counted)
+    device_get = jax.device_get
+
+    def counted_get(tree):
+        reads.append(tuple(x.shape for x in jax.tree.leaves(tree)))
+        return device_get(tree)
+    monkeypatch.setattr(jax, "device_get", counted_get)
 
     tel = engine.run([request(1)], warmup=False)
     (ev,) = tel.steps
     extra = len(op_kinds()) - len(BUILTIN_KINDS)
     assert set(BUILTIN_KINDS) <= set(op_kinds())
-    assert ev.host_fetches == len(reads) == dlrm_fetches() == 13 + 2 * extra
-    assert reads[0] == (EX.batch,)                   # the logits first
+    assert ev.host_fetches == len(reads) == dlrm_fetches() == 1
+    # the logits, then every counter, in one transfer
+    assert reads[0] == ((EX.batch,), (n_counters(),))
+    assert n_counters() == 12 + 2 * extra
     assert tel.summary()["host_fetches"] == ev.host_fetches
     d = tel.to_dict()
     assert d["schema_version"] == TELEMETRY_SCHEMA_VERSION == 3
@@ -157,3 +173,51 @@ def test_engine_spans_share_the_tracer_clock(engine):
     fetches = obs.registry.counter("repro_host_fetches_total")
     assert fetches.value(kind="dlrm", source="serving.engine") == \
         tel.summary()["host_fetches"] == 2 * dlrm_fetches()
+
+
+@pytest.fixture(scope="module")
+def llm_engine():
+    eng = ServingEngine(reduce_cfg(get_arch("llama3.2-1b")),
+                        [TenantSpec("t")], n_slots=1, max_prompt=4,
+                        max_new_tokens=2, seed=0)
+    eng.warmup()
+    return eng
+
+
+@pytest.mark.parametrize("case", ["dlrm", "dlrm_flip", "llm_decode"])
+def test_the_packed_read_gives_each_counter_as_read_one_by_one(
+        case, engine, llm_engine, monkeypatch):
+    """The counters and error total one transfer of the packed vector
+    gives equal, key for key, what ``_counters_of`` reads scalar by
+    scalar from the same step's metrics."""
+    eng = llm_engine if case == "llm_decode" else engine
+    pairs = []
+    readback = eng._readback
+
+    def both(metrics, packed, *outs):
+        host, (counters, errors) = readback(metrics, packed, *outs)
+        pairs.append(((dict(counters), errors),
+                      engine_mod._counters_of(metrics)))
+        return host, (counters, errors)
+    monkeypatch.setattr(eng, "_readback", both)
+
+    if case == "llm_decode":
+        eng.reset_state()
+        tel = eng.run([Request(rid=0, tenant="t", arrival_s=0.0,
+                               prompt_len=4, max_new_tokens=2, seed=5)],
+                      warmup=False)
+        assert [ev.kind for ev in tel.steps] == ["prefill", "decode"]
+    else:
+        inject = ([FaultInjection(step=eng.global_step, victim="bottom")]
+                  if case == "dlrm_flip" else [])
+        tel = eng.run([request(4)], warmup=False, inject=inject)
+    assert len(pairs) == len(tel.steps)
+    for (got, errors), (want, want_errors) in pairs:
+        assert got == want and errors == want_errors
+        assert set(got) == {f"{k}_{c}" for k in op_kinds()
+                            for c in ("checks", "errors")} | {
+                                "retries", "corrections"}
+        assert got["qgemm_checks"] > 0
+    ev = tel.steps[-1]
+    assert ev.errors == pairs[-1][0][1]
+    assert (ev.errors > 0) == (case == "dlrm_flip")
