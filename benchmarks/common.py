@@ -1,41 +1,14 @@
-"""Shared benchmark utilities: timing, CSV emit, DLRM shape set."""
+"""Shared benchmark utilities: CSV emit, DLRM shape set."""
 from __future__ import annotations
 
-from typing import Callable, List
+from typing import List
 
-import jax
-
-# The paper's 28 Fig. 5 DLRM GEMM shapes — canonical definition moved to
+# The paper's 28 Fig. 5 DLRM GEMM shapes — canonical definition lives in
 # the campaign subsystem (repro.campaign.spec), re-exported here for the
-# overhead benchmarks.
+# detection benchmarks.
 from repro.campaign.spec import DLRM_GEMM_SHAPES as GEMM_SHAPES  # noqa: E402,F401
 
 assert len(GEMM_SHAPES) == 28
-
-
-def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 10,
-            min_time_s: float = 0.2) -> float:
-    """Median wall seconds per call of a jitted fn (blocks on outputs).
-
-    Delegates to the campaign subsystem's helper so benchmarks/ tables and
-    campaign overhead cells share one timing methodology.
-    """
-    from repro.campaign.timing import median_time
-
-    return median_time(fn, *args, warmup=warmup, iters=iters,
-                       min_time_s=min_time_s)
-
-
-def modelled_cost(fn: Callable, *args) -> dict:
-    """Trip-aware (flops, bytes) of a jitted fn from its compiled HLO.
-
-    The wall-clock columns measure the CPU backend; these columns measure
-    the *program* (what a TPU deployment executes), via launch.costs.
-    """
-    from repro.launch.costs import analyze_hlo_text
-
-    compiled = jax.jit(fn).lower(*args).compile()
-    return analyze_hlo_text(compiled.as_text())
 
 
 class Csv:

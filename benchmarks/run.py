@@ -1,11 +1,10 @@
 """Benchmark suite entry point: ``python -m benchmarks.run [--quick]``.
 
-One benchmark per paper table/figure:
-  * gemm_overhead   — Fig. 5  (ABFT GEMM overhead, 28 DLRM shapes)
-  * eb_overhead     — Table I / Fig. 6 (ABFT EmbeddingBag overhead)
+One benchmark per paper table:
   * gemm_detection  — Table II (simulated-error detection accuracy, GEMM)
   * eb_detection    — Table III (simulated-error detection accuracy, EB)
-  * roofline_table  — §Roofline (from dry-run artifacts, if present)
+
+Chip timings come from the chip benchmark (``BENCHMARK.json``).
 """
 from __future__ import annotations
 
@@ -21,25 +20,18 @@ def main():
                     help="run a single benchmark by name")
     args = ap.parse_args()
 
-    from benchmarks import (eb_detection, eb_overhead, gemm_detection,
-                            gemm_overhead, roofline_table)
+    from benchmarks import eb_detection, gemm_detection
 
     benches = {
-        "gemm_overhead": gemm_overhead.main,
-        "eb_overhead": eb_overhead.main,
         "gemm_detection": gemm_detection.main,
         "eb_detection": eb_detection.main,
-        "roofline_table": roofline_table.main,
     }
     if args.only:
         benches = {args.only: benches[args.only]}
     for name, fn in benches.items():
         print(f"\n=== {name} ===", flush=True)
         t0 = time.time()
-        try:
-            fn(quick=args.quick)
-        except FileNotFoundError as e:
-            print(f"({name} skipped: {e})")
+        fn(quick=args.quick)
         print(f"=== {name} done in {time.time()-t0:.1f}s ===", flush=True)
 
 

@@ -8,7 +8,7 @@ Run from the repository root.  Without options it serves seeded DLRM
 lookups through :class:`repro.serving.ServingEngine` at the paper's table
 geometry (26 tables x 4,000,000 rows x 128, int8; Table I pooling 100 and
 batch 10) on two plan lanes: the default plan (fused Pallas kernels) and
-``qgemm:scheme=unfused,embedding_bag:scheme=xla`` (the XLA reference of
+``qgemm:scheme=xla,embedding_bag:scheme=xla`` (the XLA reference of
 the same integer arithmetic, on the same tables).  It checks that their
 pooled EmbeddingBag outputs and their logits agree, that the clean pass
 raises no ABFT flag, and that an injected weight flip is detected.
@@ -184,7 +184,7 @@ def dlrm_phase(dev) -> None:
     log(f"rows: {why}")
 
     ref_plan = default_plan().with_rules(*ProtectionPlan.parse(
-        "qgemm:scheme=unfused,embedding_bag:scheme=xla").rules)
+        "qgemm:scheme=xla,embedding_bag:scheme=xla").rules)
     tenants = [TenantSpec("pallas", default_plan()),
                TenantSpec("xla", dataclasses.replace(ref_plan, name="xla"))]
     t0 = time.perf_counter()

@@ -104,19 +104,19 @@ def thresholds_specs(seed: int = 0,
 
 def pallas_specs(seed: int = 0, quick: bool = False,
                  samples: int = 0) -> List[CampaignSpec]:
-    """Fused-kernel detection parity (ROADMAP open item 1): run the SAME
-    bit-flip grid through the fused Pallas implementation and the XLA
-    reference schemes, so the artifact holds fused vs unfused vs packed
-    detection rates side by side.  Cell seeds derive from cell ids (which
-    include the target name), so the fused and unfused cells draw
+    """Fused-kernel detection parity: run the SAME bit-flip grid through
+    the fused Pallas implementation and the XLA reference, so the
+    artifact holds fused vs packed detection rates side by side.  Cell
+    seeds derive from cell ids (which include the target name), so the
+    fused and reference cells draw
     *different* fault samples — the parity gate is therefore statistical:
     overlapping 95% Wilson intervals on the same grid point
     (:func:`repro.campaign.diff` compares detection the same way).  A
     deterministic bit-exact parity check (same flips through both paths)
     lives in tests/test_kernels.py; this grid measures at campaign scale
-    and times the fused kernel (interpret mode on CPU — honest wall-clock
-    for parity, not a TPU latency claim; the roofline benchmark models
-    the TPU traffic).
+    and times the fused kernel (interpret mode on CPU — wall-clock for
+    parity, not a TPU latency claim; chip numbers come from the chip
+    benchmark).
 
     The EB cells run BOTH targets at the pallas-sized shape so cells stay
     comparable (interpret-mode emulation makes the default EB shape
@@ -124,7 +124,7 @@ def pallas_specs(seed: int = 0, quick: bool = False,
     n = samples or (400 if quick else 800)
     gemm = CampaignSpec(
         name="pallas-gemm",
-        targets=("gemm_pallas", "gemm_packed", "gemm_unfused"),
+        targets=("gemm_pallas", "gemm_packed"),
         fault_models=("bitflip",),
         bit_bands=("all",),
         shapes=((20, 256, 512),),

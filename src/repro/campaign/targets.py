@@ -205,43 +205,12 @@ register_target(InjectableTarget(
     overhead_phases=_gemm_phases))
 
 
-_UNFUSED = ResolvedRule(scheme="unfused")
-
-
-def _gemm_unfused_trial(state, plan: CellPlan, key: jax.Array):
-    # BLAS-2 verification path (§IV-A3 step ③), amortized clean encode
-    b_bad = apply_fault(key, state["b"], plan)
-    _, check = QGEMM(_gemm_repack(state, b_bad), state["a"],
-                     rule=_UNFUSED)
-    return check.err_count > 0, jnp.any(b_bad != state["b"])
-
-
-def _gemm_unfused_overhead(state, plan: CellPlan):
-    a = state["a"]
-    b_packed = _gemm_repack(state, state["b"])
-
-    def protected():
-        return QGEMM(b_packed, a, rule=_UNFUSED)[0]
-
-    def unprotected():
-        return QGEMM.unprotected(b_packed, a)
-
-    return protected, unprotected
-
-
-register_target(InjectableTarget(
-    name="gemm_unfused",
-    build=_gemm_build, trial=_gemm_unfused_trial, clean=_gemm_clean,
-    default_shapes=((20, 256, 512),), shape_arity=3,
-    analytic_bound=_gemm_bound, overhead=_gemm_unfused_overhead,
-    overhead_phases=_gemm_phases))
-
-
-# Fused Pallas implementation as a measured third scheme: identical build
-# (so cells differ only in execution path), trials routed through
+# Fused Pallas implementation as a measured scheme: identical build (so
+# cells differ only in execution path), trials routed through
 # scheme="pallas" — interpret mode on CPU, the real kernel on TPU.  The
-# --grid pallas campaign runs this next to gemm_packed/gemm_unfused on the
-# same flip grid and gates on detection parity (overlapping Wilson CIs).
+# --grid pallas campaign runs this next to gemm_packed (the XLA reference
+# off the TPU) on the same flip grid and gates on detection parity
+# (overlapping Wilson CIs).
 
 _PALLAS = ResolvedRule(scheme="pallas")
 

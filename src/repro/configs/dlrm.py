@@ -7,8 +7,8 @@ EmbeddingBag lookups, a feature interaction, top MLP -> CTR logit.
 rows, pooling 100, batch 10, with the pairwise dot interaction of DLRMv1.
 :data:`DCNV2` is MLPerf's DLRMv2 (DLRM-DCNv2): 26 tables of 3 to 40M rows,
 each with its own multi-hot size, and a low-rank cross network in place of
-the dot interaction.  The GEMM shapes exercised by
-benchmarks/gemm_overhead follow Fig. 5."""
+the dot interaction.  The GEMM shapes of the detection benchmarks and
+campaign follow Fig. 5 (``repro.campaign.spec.DLRM_GEMM_SHAPES``)."""
 import dataclasses
 from typing import Union
 

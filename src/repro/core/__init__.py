@@ -20,7 +20,6 @@ from repro.core.abft_gemm import (
     AbftGemmOut,
     abft_qgemm,
     abft_qgemm_packed,
-    abft_qgemm_unfused,
     correct_single_error,
     correct_weight_flip,
     detect_prob_b_bitflip,
@@ -64,7 +63,7 @@ from repro.core.policy import (
 __all__ = [
     "MOD", "LANE", "AbftGemmOut",
     "encode_weight_checksum", "encode_activation_checksum",
-    "abft_qgemm", "abft_qgemm_packed", "abft_qgemm_unfused",
+    "abft_qgemm", "abft_qgemm_packed",
     "pack_encoded_b", "verify_rows", "correct_single_error",
     "encode_weight_colsum", "correct_weight_flip",
     "detect_prob_b_bitflip", "detect_prob_b_random", "detect_prob_c_random",

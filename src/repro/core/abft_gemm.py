@@ -111,24 +111,6 @@ def abft_qgemm_packed(a_q: jax.Array, b_packed: jax.Array,
     return AbftGemmOut(c, err_rows, err_count)
 
 
-def abft_qgemm_unfused(a_q: jax.Array, b_q: jax.Array,
-                       mod: int = MOD) -> AbftGemmOut:
-    """The BLAS-2 baseline the paper argues *against* (§IV-A3 step ③).
-
-    Kept for benchmarking the packing trick: the checksum product is a
-    separate matrix-vector product.
-    """
-    checksum = encode_weight_checksum(b_q, mod)
-    c = jax.lax.dot_general(
-        a_q, b_q, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    check_col = jax.lax.dot_general(
-        a_q, checksum, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    err_rows, err_count = verify_rows(c, check_col, mod)
-    return AbftGemmOut(c, err_rows, err_count)
-
-
 def encode_activation_checksum(a_q: jax.Array) -> jax.Array:
     """Column-side encoding: exact int32 column sums of A ([m, k] -> [k]).
 

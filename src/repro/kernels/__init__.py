@@ -16,5 +16,5 @@ we provide the TPU-native equivalents:
   hillclimb 1, iteration 5).
 
 ``ref.py`` holds the pure-jnp oracles; ``ops.py`` the jit'd public wrappers
-(with ``interpret=`` plumbed through for CPU validation).
+(the Pallas kernel on a TPU, the XLA reference elsewhere, unless told).
 """

@@ -1,14 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-``use_pallas`` selects the Pallas path vs. the pure-XLA path (identical
-math, real HLO cost model).  On a CPU host the default is the XLA path; on
-TPU it is the Pallas path.
-
-Dispatch precedence (all three wrappers): an EXPLICIT ``use_pallas``
-(True/False) always wins.  Only when it is None does ``interpret=True``
-(validate the kernel body on CPU) or a TPU backend select the Pallas path.
-Interpret mode is a CPU-only tool: on a TPU the kernels always compile
-with Mosaic, whatever ``interpret`` says.
+Every wrapper takes one ``use_pallas``: True runs the Pallas kernel
+(compiled with Mosaic on a TPU, in interpret mode anywhere else), False
+the plain XLA reference (identical math), and None lets the platform
+decide: Pallas on a TPU, the XLA reference elsewhere.
 
 These wrappers are the operator surface the :mod:`repro.protect` adapters
 dispatch to — layer code should not call them directly.
@@ -30,20 +25,13 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def _use_pallas(use_pallas: Optional[bool], interpret: bool) -> bool:
-    """Resolve the scheme: explicit beats auto, auto = interpret-or-TPU.
-
-    (The old ``if use_pallas or interpret`` sent ``use_pallas=False,
-    interpret=True`` to the Pallas kernel — an explicit XLA request lost.)
-    """
-    if use_pallas is not None:
-        return use_pallas
-    return interpret or _on_tpu()
+def _use_pallas(use_pallas: Optional[bool]) -> bool:
+    """An explicit choice wins; None means Pallas exactly on a TPU."""
+    return _on_tpu() if use_pallas is None else use_pallas
 
 
 def abft_qgemm(a_q: jax.Array, b_packed: jax.Array, *,
-               use_pallas: Optional[bool] = None, interpret: bool = False,
-               with_colcheck: bool = False,
+               use_pallas: Optional[bool] = None, with_colcheck: bool = False,
                bm: int = 128, bn: int = 128, bk: int = 128):
     """ABFT int8 GEMM against a packed B'. -> (C int32, err_rows int32 [m]).
 
@@ -57,7 +45,7 @@ def abft_qgemm(a_q: jax.Array, b_packed: jax.Array, *,
     call sites.  On the Pallas path the matvec is fused into the kernel's
     per-tile pass, so the ``correct`` policy pays no second read of A/B'.
     """
-    if _use_pallas(use_pallas, interpret):
+    if _use_pallas(use_pallas):
         from repro.kernels.abft_qgemm import abft_qgemm_pallas
         return abft_qgemm_pallas(a_q, b_packed, bm=bm, bn=bn, bk=bk,
                                  interpret=not _on_tpu(),
@@ -75,10 +63,9 @@ def abft_qgemm(a_q: jax.Array, b_packed: jax.Array, *,
 
 def abft_embedding_bag(table_q, alphas, betas, indices, rowsums,
                        weights=None, *, rel_bound: float = EB_REL_BOUND,
-                       use_pallas: Optional[bool] = None,
-                       interpret: bool = False):
+                       use_pallas: Optional[bool] = None):
     """EB forward + Eq. (5) check. -> AbftEbOut(r, err_bags, err_count)."""
-    if _use_pallas(use_pallas, interpret):
+    if _use_pallas(use_pallas):
         from repro.kernels.abft_embeddingbag import abft_eb_pallas
         r, rsum = abft_eb_pallas(table_q, alphas, betas, indices, weights,
                                  interpret=not _on_tpu())
@@ -92,21 +79,20 @@ def abft_embedding_bag(table_q, alphas, betas, indices, rowsums,
                          weights, rel_bound)
 
 
-def eb_slots(indices, d: int, *, use_pallas: Optional[bool] = None,
-             interpret: bool = False) -> int:
+def eb_slots(indices, d: int, *,
+             use_pallas: Optional[bool] = None) -> int:
     """Slots (bag positions, −1 pads included) that
     :func:`abft_embedding_bag` runs on ``indices``: the Pallas kernel's
     grid, or every bag position of the XLA gather."""
-    if _use_pallas(use_pallas, interpret):
+    if _use_pallas(use_pallas):
         from repro.kernels.abft_embeddingbag import grid_slots
         return grid_slots(indices, d)
     return sum(i.size for i in jax.tree.leaves(indices))
 
 
-def quantize_rows(x: jax.Array, *, use_pallas: Optional[bool] = None,
-                  interpret: bool = False):
+def quantize_rows(x: jax.Array, *, use_pallas: Optional[bool] = None):
     """Per-row signed-int8 dynamic quantization. -> (q, alpha, beta)."""
-    if _use_pallas(use_pallas, interpret):
+    if _use_pallas(use_pallas):
         from repro.kernels.quantize_rows import quantize_rows_pallas
         return quantize_rows_pallas(x, interpret=not _on_tpu())
     return _ref.quantize_rows_ref(x)

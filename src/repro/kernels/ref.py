@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import LANE, MOD, embedding_bag
+from repro.core import MOD, abft_qgemm_packed, embedding_bag
 
 
 def int8_dot(a_q: jax.Array, b_q: jax.Array) -> jax.Array:
@@ -22,12 +22,8 @@ def int8_dot(a_q: jax.Array, b_q: jax.Array) -> jax.Array:
 
 def abft_qgemm_ref(a_q: jax.Array, b_packed: jax.Array, mod: int = MOD):
     """Oracle for kernels.abft_qgemm: (C int32 [m,n], err_rows int32 [m])."""
-    n = b_packed.shape[1] - LANE
-    c_full = int8_dot(a_q, b_packed)
-    c = c_full[:, :n]
-    check = c_full[:, n] % mod
-    rowsum = jnp.sum(c % mod, axis=1) % mod
-    return c, (rowsum != check).astype(jnp.int32)
+    out = abft_qgemm_packed(a_q, b_packed, mod)
+    return out.c, out.err_rows.astype(jnp.int32)
 
 
 def abft_eb_ref(table_q, alphas, betas, indices, weights=None):

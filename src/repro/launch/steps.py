@@ -110,8 +110,8 @@ def make_train_step_deferred(model: Model, ctx: Ctx, mesh, *,
 
     Returns (state, comm, batch) -> (state, comm, metrics).  ``comm`` is the
     per-device error-feedback residual tree with a leading data-axis dim
-    (init via :func:`init_comm_state`); pass ``comm=None`` trees when
-    ``compress=False``.
+    (``init_train_state(..., compress=True)["comm"]`` broadcast over the
+    data axis); pass ``comm=None`` trees when ``compress=False``.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -193,174 +193,6 @@ def make_train_step_deferred(model: Model, ctx: Ctx, mesh, *,
         manual_axes=set(data_axes))
 
 
-def make_train_step_zero1(model: Model, ctx: Ctx, mesh, *,
-                          accum: int = 1, peak_lr: float = 3e-4,
-                          warmup: int = 100, total_steps: int = 10000,
-                          max_grad_norm: float = 1.0,
-                          axes=("data", "model")):
-    """Pure data parallelism + ZeRO-1 over ALL mesh axes (hillclimb 2,
-    iteration 4 — the right scheme for models whose bf16 params fit
-    replicated on one chip, e.g. granite 3B on a 16 GB v5e).
-
-    * no tensor parallelism -> ZERO per-microbatch collectives;
-    * bf16 params replicated; f32 master/m/v live as FLAT SHARDS
-      (1/N each — flat layout sidesteps per-leaf divisibility);
-    * per step: one f32 gradient reduce-scatter, Adam on the local shard,
-      one bf16 param all-gather.
-
-    state = {"params": bf16 tree (replicated),
-             "opt": {"master","m","v": f32 [D/N] flat shards}, "step"}.
-    Returns the shard_map'd (state, batch) -> (state, metrics).
-    """
-    import numpy as np
-    from jax.flatten_util import ravel_pytree
-    from jax.sharding import PartitionSpec as P
-
-    msizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    n_shards = 1
-    for a in axes:
-        n_shards *= msizes[a]
-    axis = tuple(axes) if len(axes) > 1 else axes[0]
-
-    def loss_fn(params, mb):
-        loss, (metrics, rep) = model.loss(params, mb, ctx)
-        return loss, metrics
-
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-
-    def local_grads(params, batch):
-        if accum == 1:
-            (loss, metrics), grads = grad_fn(params, batch)
-            return grads, loss, metrics
-        micro = jax.tree.map(
-            lambda x: x.reshape((accum, x.shape[0] // accum) + x.shape[1:]),
-            batch)
-
-        def body(g_acc, mb):
-            (l, m), g = grad_fn(params, mb)
-            return jax.tree.map(
-                lambda a_, b_: a_ + b_.astype(jnp.float32), g_acc, g), (l, m)
-
-        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        grads, (losses, metrics) = jax.lax.scan(body, g0, micro)
-        grads = jax.tree.map(lambda g: g / accum, grads)
-        metrics = jax.tree.map(
-            lambda x: jnp.mean(x.astype(jnp.float32)), metrics)
-        return grads, jnp.mean(losses), metrics
-
-    def step(state, batch):
-        params = state["params"]
-        grads, loss, metrics = local_grads(params, batch)
-        # ravel in the gradients' own (bf16) dtype — an f32 staging copy
-        # costs 2x params of HBM (measured: +13.5 GiB on granite); the
-        # bf16 reduce-scatter is the standard TPU-pod trade, and the f32
-        # conversion happens on the 1/N local shard only.
-        gflat, unravel = ravel_pytree(
-            jax.tree.map(lambda g: g.astype(jnp.bfloat16), grads))
-        d = gflat.shape[0]
-        pad = (-d) % n_shards
-        gflat = jnp.pad(gflat, (0, pad)) / n_shards
-        gshard = jax.lax.psum_scatter(
-            gflat.reshape(n_shards, -1), axis, scatter_dimension=0,
-            tiled=False).astype(jnp.float32)
-
-        # global-norm clip from shard-local sum of squares
-        gnorm = jnp.sqrt(jax.lax.psum(jnp.sum(gshard * gshard), axis))
-        scale = jnp.minimum(1.0, max_grad_norm / jnp.maximum(gnorm, 1e-9))
-        gshard = gshard * scale
-
-        lr = warmup_cosine(state["step"], peak=peak_lr, warmup=warmup,
-                           total=total_steps)
-        b1, b2, eps, wd = 0.9, 0.95, 1e-8, 0.1
-        cnt = (state["step"] + 1).astype(jnp.float32)
-        m = b1 * state["opt"]["m"] + (1 - b1) * gshard
-        v = b2 * state["opt"]["v"] + (1 - b2) * gshard * gshard
-        mh = m / (1 - b1 ** cnt)
-        vh = v / (1 - b2 ** cnt)
-        master = state["opt"]["master"]
-        master = master - lr * (mh / (jnp.sqrt(vh) + eps) + wd * master)
-
-        # ONE collective for params: bf16 all-gather of updated shards
-        pflat = jax.lax.all_gather(
-            master.astype(jnp.bfloat16), axis, tiled=True)
-        if pad:
-            pflat = pflat[:-pad]
-        new_params = jax.tree.map(
-            lambda a, ref: a.astype(ref.dtype), unravel(pflat), params)
-
-        new_state = {"params": new_params,
-                     "opt": {"master": master, "m": m, "v": v},
-                     "step": state["step"] + 1}
-        metrics = dict(metrics)
-        metrics.update({"grad_norm": gnorm, "lr": lr,
-                        "loss_final": jax.lax.pmean(loss, axis)})
-        metrics = jax.tree.map(
-            lambda x: (jax.lax.psum(x, axis)
-                       if jnp.issubdtype(jnp.asarray(x).dtype, jnp.integer)
-                       else jax.lax.pmean(
-                           jnp.asarray(x, jnp.float32), axis)), metrics)
-        return new_state, metrics
-
-    batch_spec = P(axis)
-    state_spec = {"params": P(), "opt": P(axis), "step": P()}
-    from repro.sharding import shard_map
-    return shard_map(
-        step, mesh=mesh,
-        in_specs=(state_spec, batch_spec),
-        out_specs=(state_spec, P()),
-        manual_axes=set(axes))
-
-
-def zero1_state_sds(model: Model, mesh, axes=("data", "model")):
-    """ShapeDtypeStructs + shardings for the ZeRO-1 state."""
-    from jax.flatten_util import ravel_pytree  # noqa: F401
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.sharding import values_of
-
-    msizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    n_shards = 1
-    for a in axes:
-        n_shards *= msizes[a]
-    params_lp = jax.eval_shape(
-        lambda: model.init(jax.random.key(0), dtype=jnp.bfloat16))
-    params_sds = values_of(params_lp)
-    d = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(params_sds))
-    d_pad = d + ((-d) % n_shards)
-    shard = jax.ShapeDtypeStruct((d_pad // n_shards,), jnp.float32)
-    state_sds = {
-        "params": params_sds,
-        "opt": {"master": jax.ShapeDtypeStruct((d_pad,), jnp.float32),
-                "m": jax.ShapeDtypeStruct((d_pad,), jnp.float32),
-                "v": jax.ShapeDtypeStruct((d_pad,), jnp.float32)},
-        "step": jax.ShapeDtypeStruct((), jnp.int32),
-    }
-    axis = tuple(axes) if len(axes) > 1 else axes[0]
-    repl = NamedSharding(mesh, P())
-    state_sh = {
-        "params": jax.tree.map(lambda _: repl, params_sds),
-        "opt": {"master": NamedSharding(mesh, P(axis)),
-                "m": NamedSharding(mesh, P(axis)),
-                "v": NamedSharding(mesh, P(axis))},
-        "step": repl,
-    }
-    del shard
-    return state_sds, state_sh, params_lp
-
-
-import numpy as np  # noqa: E402  (zero1_state_sds)
-
-
-def init_comm_state(params_sds, n_data: int):
-    """Per-device error-feedback residuals, leading data-axis dim."""
-    from repro.runtime.compression import CompressionState
-
-    return CompressionState(error=jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct((n_data,) + tuple(
-            jnp.shape(p) if not hasattr(p, "shape") else p.shape),
-            jnp.float32), params_sds))
-
-
 def make_prefill_step(model: Model, ctx: Ctx, cache_len: int):
     """(params, batch) -> (next_token [B], cache, metrics)."""
 
@@ -388,7 +220,7 @@ def make_decode_step(model: Model, ctx: Ctx):
 
 def init_train_state(model: Model, key, *, dtype=jnp.float32,
                      compress: bool = False):
-    """Concrete state (examples / small runs). Dry-run uses eval_shape.
+    """Concrete state (examples / small runs).
 
     ``compress=True`` adds the ``"comm"`` error-feedback residual tree for
     ``make_train_step(compress=True)``."""

@@ -7,8 +7,9 @@ The quantized linear runs Fig. 1 end to end:
 
 Weights are packed once at init/conversion (amortized encoding, §IV-A1).
 All verification goes through :func:`repro.protect.protected_call` — the
-plan in ``ctx`` decides scheme (packed / unfused / Pallas), policy
-(log / recompute / correct / abort), and on/off per call site ``name``.
+plan in ``ctx`` decides scheme (Pallas kernel or XLA reference; by
+default Pallas exactly on a TPU), policy (log / recompute / correct /
+abort), and on/off per call site ``name``.
 """
 from __future__ import annotations
 

@@ -7,9 +7,9 @@ policy/threshold" for the whole stack:
   (``"qgemm/attn.*:policy=recompute,embedding_bag:off"``), parseable from
   CLI strings and config dicts;
 * :class:`~repro.protect.ops.ProtectedOp` adapters — uniform
-  ``encode / __call__ / unprotected`` over int8 GEMM (packed / unfused /
-  Pallas via :mod:`repro.kernels.ops`), float GEMM, EmbeddingBag, and the
-  quantized KV cache;
+  ``encode / __call__ / unprotected`` over int8 GEMM and EmbeddingBag
+  (Pallas or the XLA reference via :mod:`repro.kernels.ops`), float GEMM,
+  and the quantized KV cache;
 * :func:`protected_call` — the single runtime every layer call site goes
   through (rule resolution, scheme dispatch, per-op policy application:
   log / recompute / correct / abort);

@@ -13,9 +13,10 @@ Every ABFT-protected operator family exposes one uniform surface:
 
 Adapters registered here (``qgemm``, ``float_gemm``, ``embedding_bag``,
 ``kv_cache``) dispatch through :mod:`repro.kernels.ops` where a Pallas
-kernel exists, so scheme selection (``packed`` / ``unfused`` / ``pallas``)
-is a plan concern, not a call-site concern.  Register a custom adapter with
-:func:`register_op`; its name becomes a report key and a plan pattern.
+kernel exists, so scheme selection (``xla`` / ``pallas``, or the
+platform's choice) is a plan concern, not a call-site concern.  Register a
+custom adapter with :func:`register_op`; its name becomes a report key and
+a plan pattern.
 """
 from __future__ import annotations
 
@@ -28,15 +29,29 @@ import jax.numpy as jnp
 from repro.core import (EB_REL_BOUND, LANE, QuantKV, abft_gemm_f32,
                         attend_quantized, correct_single_error,
                         correct_weight_flip, dequantize_kv, embedding_bag,
-                        encode_activation_checksum, encode_weight_f32,
-                        pack_encoded_b, quantize_kv_rows, table_rowsums,
-                        update_kv_row, verify_rows)
+                        encode_weight_f32, pack_encoded_b, quantize_kv_rows,
+                        table_rowsums, update_kv_row)
 from repro.core.policy import register_op_kind
 from repro.kernels import ops as kops
 from repro.paging.cache import attend_paged, paged_append
 from repro.protect.plan import ResolvedRule
 
 _DEFAULT_RULE = ResolvedRule()
+
+#: the schemes of an op with a Pallas kernel: ``xla`` the plain reference,
+#: ``pallas`` the kernel (interpret mode off the TPU); no scheme lets the
+#: platform decide (Pallas on a TPU, the reference elsewhere)
+KERNEL_SCHEMES = ("xla", "pallas")
+
+
+def _scheme_use_pallas(op, scheme: Optional[str]) -> Optional[bool]:
+    """A rule's scheme as :mod:`repro.kernels.ops`' ``use_pallas``."""
+    if scheme is None:
+        return None
+    if scheme not in KERNEL_SCHEMES:
+        raise ValueError(f"unknown {op.name} scheme {scheme!r}; "
+                         f"have {op.schemes}")
+    return scheme == "pallas"
 
 
 class Check(NamedTuple):
@@ -69,9 +84,8 @@ class ProtectedOp(Protocol):
 class QGemmOp:
     """Quantized GEMM: encoded = packed B' (int8 [k, n+LANE]), input = A_q.
 
-    Schemes: ``packed`` (fused checksum column, Pallas on TPU / XLA ref on
-    CPU), ``pallas`` (force the Pallas kernel, interpret-mode off-TPU),
-    ``unfused`` (the BLAS-2 baseline the paper argues against §IV-A3).
+    Schemes: :data:`KERNEL_SCHEMES`; either computes the checksum column
+    fused into the one GEMM against B'.
 
     ``encoded`` may also be ``(packed, colsum_ref)`` where ``colsum_ref``
     is the exact int32 column sums of the clean B block (amortized at
@@ -82,7 +96,7 @@ class QGemmOp:
     and the exact delta (:func:`repro.core.correct_weight_flip`).
     """
     name = "qgemm"
-    schemes = ("packed", "pallas", "unfused")
+    schemes = KERNEL_SCHEMES
     supports_correct = True
     lane = LANE
 
@@ -115,32 +129,10 @@ class QGemmOp:
 
     def __call__(self, encoded, a_q, *, rule: ResolvedRule = _DEFAULT_RULE):
         packed, colsum_ref = self._unpack(encoded)
-        scheme = rule.scheme or "packed"
         want_col = rule.policy == "correct"
-        n = self.out_dim(packed)
-        if scheme == "unfused":
-            b_q = packed[:, :n]
-            checksum = packed[:, n]                        # lane 0 of block
-            c = jax.lax.dot_general(a_q, b_q, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.int32)
-            check_col = jax.lax.dot_general(
-                a_q, checksum, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-            err_rows, err = verify_rows(c, check_col)
-            col_check = None
-            if want_col:
-                col_check = jax.lax.dot_general(
-                    encode_activation_checksum(a_q),
-                    b_q.astype(jnp.int32), (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-            return c, Check(err, err_rows,
-                            self._aux(col_check, a_q, packed, colsum_ref))
-        if scheme not in ("packed", "pallas"):
-            raise ValueError(f"unknown qgemm scheme {scheme!r}; "
-                             f"have {self.schemes}")
-        use_pallas = True if scheme == "pallas" else None
-        out = kops.abft_qgemm(a_q, packed, use_pallas=use_pallas,
-                              with_colcheck=want_col)
+        out = kops.abft_qgemm(
+            a_q, packed, use_pallas=_scheme_use_pallas(self, rule.scheme),
+            with_colcheck=want_col)
         if want_col:
             c, err_rows, col_check = out
         else:
@@ -218,7 +210,7 @@ class EmbeddingBagOp:
     up every table and counts one check a table.  The report's
     ``eb_slots`` counts the slots the lookup ran (the kernel's grid)."""
     name = "embedding_bag"
-    schemes = ("xla", "pallas")
+    schemes = KERNEL_SCHEMES
     supports_correct = False
     default_rel_bound = EB_REL_BOUND
 
@@ -232,15 +224,7 @@ class EmbeddingBagOp:
         table_q, alphas, betas, rowsums = encoded
         rel = self.default_rel_bound if rule.rel_bound is None \
             else rule.rel_bound
-        if rule.scheme is None:
-            use_pallas = None                      # auto: Pallas on TPU
-        elif rule.scheme == "pallas":
-            use_pallas = True
-        elif rule.scheme == "xla":
-            use_pallas = False
-        else:
-            raise ValueError(f"unknown embedding_bag scheme "
-                             f"{rule.scheme!r}; have {self.schemes}")
+        use_pallas = _scheme_use_pallas(self, rule.scheme)
         out = kops.abft_embedding_bag(table_q, alphas, betas, indices,
                                       rowsums, weights, rel_bound=rel,
                                       use_pallas=use_pallas)

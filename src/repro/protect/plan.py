@@ -16,11 +16,11 @@ serialize to/from dicts for configs, and parse from compact CLI strings::
     *:policy=log                          # protect everything, log-only
     embedding_bag:off                     # ...but EB protection disabled
     qgemm:policy=recompute:retries=2      # int8 GEMMs retry on detection
-    qgemm/attn.*:scheme=unfused           # attention projections, BLAS-2
+    qgemm/attn.*:scheme=xla               # attention projections, XLA ref
     embedding_bag:rel_bound=1e-4          # looser Eq. (5) threshold
 
 joined with commas:
-``"*:policy=log,embedding_bag:off,qgemm/attn.*:scheme=unfused"``.
+``"*:policy=log,embedding_bag:off,qgemm/attn.*:scheme=xla"``.
 """
 from __future__ import annotations
 
@@ -55,7 +55,7 @@ class OpRule:
     pattern: str = "*"
     enabled: Optional[bool] = None
     scheme: Optional[str] = None          # adapter-specific (e.g. qgemm:
-    policy: Optional[str] = None          #   packed | unfused | pallas)
+    policy: Optional[str] = None          #   xla | pallas)
     rel_bound: Optional[float] = None     # float-checked ops' threshold
     max_retries: Optional[int] = None     # recompute policy budget
     threshold: Optional[str] = None       # static | adaptive (None=inherit)

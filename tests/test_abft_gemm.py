@@ -28,14 +28,6 @@ def test_no_false_positives_and_correct_c(rng, m, k, n):
     assert not bool(out.err_rows.any())
 
 
-def test_fused_equals_unfused(rng):
-    a, b = _rand_ab(rng, 8, 32, 16)
-    f = ag.abft_qgemm(a, b)
-    u = ag.abft_qgemm_unfused(a, b)
-    np.testing.assert_array_equal(np.asarray(f.c), np.asarray(u.c))
-    assert int(f.err_count) == int(u.err_count) == 0
-
-
 def test_packed_layout_lane_aligned(rng):
     _, b = _rand_ab(rng, 1, 16, 40)
     packed = ag.pack_encoded_b(b)

@@ -127,7 +127,7 @@ def test_two_row_halves_add_up_to_the_uncut_lookup():
         out = abft_embedding_bag(
             t, jnp.asarray(alphas[lo:hi]), jnp.asarray(betas[lo:hi]),
             tuple(jnp.asarray(i, jnp.int32) for i in ids), table_rowsums(t),
-            use_pallas=True, interpret=True)
+            use_pallas=True)
         assert int(out.err_count) == 0
         return np.asarray(out.r)
 

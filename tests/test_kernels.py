@@ -73,7 +73,7 @@ def test_qgemm_ops_dispatch_xla_path(rng):
     b = jnp.asarray(rng.integers(-128, 128, size=(32, 16)), jnp.int8)
     bp = pack_encoded_b(b)
     c1, e1 = ops.abft_qgemm(a, bp, use_pallas=False)
-    c2, e2 = ops.abft_qgemm(a, bp, interpret=True)
+    c2, e2 = ops.abft_qgemm(a, bp, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
 
@@ -82,8 +82,8 @@ def test_qgemm_ops_dispatch_xla_path(rng):
 
 def test_qgemm_kernel_uint8_matches_ref(rng):
     # regression: the old wrapper did a bare astype(int8), silently
-    # reinterpreting activations >= 128 as negative.  This is the exact
-    # distribution benchmarks/gemm_overhead.py generates.
+    # reinterpreting activations >= 128 as negative: uint8 activations
+    # with half their values at or above 128.
     m, k, n = 20, 256, 512
     a = jnp.asarray(rng.integers(0, 256, size=(m, k)), jnp.uint8)
     assert int(jnp.max(a)) >= 128            # the wraparound-triggering half
@@ -190,7 +190,7 @@ def test_qgemm_ops_colcheck_paths_agree(rng):
     c_x, e_x, col_x = ops.abft_qgemm(a, bp, use_pallas=False,
                                      with_colcheck=True)
     c_p, e_p, col_p = ops.abft_qgemm(a, bp, use_pallas=True,
-                                     interpret=True, with_colcheck=True)
+                                     with_colcheck=True)
     np.testing.assert_array_equal(np.asarray(c_x), np.asarray(c_p))
     np.testing.assert_array_equal(np.asarray(e_x), np.asarray(e_p))
     np.testing.assert_array_equal(np.asarray(col_x), np.asarray(col_p))
@@ -205,7 +205,7 @@ def test_qgemm_correct_policy_pallas_scheme(rng):
     c_p, chk_p = QGEMM(packed, a,
                        rule=ResolvedRule(scheme="pallas", policy="correct"))
     c_x, chk_x = QGEMM(packed, a,
-                       rule=ResolvedRule(scheme="packed", policy="correct"))
+                       rule=ResolvedRule(scheme="xla", policy="correct"))
     np.testing.assert_array_equal(np.asarray(c_p), np.asarray(c_x))
     np.testing.assert_array_equal(np.asarray(chk_p.aux),
                                   np.asarray(chk_x.aux))
@@ -214,8 +214,7 @@ def test_qgemm_correct_policy_pallas_scheme(rng):
 # ------------------- ops dispatch: explicit scheme wins ---------------------
 
 def test_ops_explicit_false_beats_interpret(rng, monkeypatch):
-    # use_pallas=False must take the XLA path even with interpret=True —
-    # the old `if use_pallas or interpret` sent it to the kernel anyway.
+    # use_pallas=False must take the XLA path whatever the platform.
     # Poison the kernel entry points; the XLA path must never touch them.
     import repro.kernels.abft_embeddingbag as eb_mod
     import repro.kernels.abft_qgemm as qg_mod
@@ -231,7 +230,7 @@ def test_ops_explicit_false_beats_interpret(rng, monkeypatch):
     a = jnp.asarray(rng.integers(-128, 128, size=(4, 32)), jnp.int8)
     b = jnp.asarray(rng.integers(-128, 128, size=(32, 16)), jnp.int8)
     bp = pack_encoded_b(b)
-    c, err = ops.abft_qgemm(a, bp, use_pallas=False, interpret=True)
+    c, err = ops.abft_qgemm(a, bp, use_pallas=False)
     assert int(err.sum()) == 0
 
     from repro.core.abft_embedding import table_rowsums
@@ -240,19 +239,19 @@ def test_ops_explicit_false_beats_interpret(rng, monkeypatch):
     be = jnp.asarray(rng.uniform(-0.1, 0.1, size=64), jnp.float32)
     idx = jnp.asarray(rng.integers(0, 64, size=(2, 8)), jnp.int32)
     out = ops.abft_embedding_bag(t, al, be, idx, table_rowsums(t),
-                                 use_pallas=False, interpret=True)
+                                 use_pallas=False)
     assert int(out.err_count) == 0
 
     x = jnp.asarray(rng.normal(size=(4, 64)), jnp.float32)
-    q, _, _ = ops.quantize_rows(x, use_pallas=False, interpret=True)
+    q, _, _ = ops.quantize_rows(x, use_pallas=False)
     assert q.dtype == jnp.int8
 
 
-# ------------- fused vs unfused: deterministic detection parity -------------
+# ----------- fused vs reference: deterministic detection parity ------------
 
-def test_qgemm_fused_unfused_err_parity(rng):
+def test_qgemm_fused_reference_err_parity(rng):
     # the SAME stale-checksum flips through the fused Pallas path and the
-    # BLAS-2 unfused scheme: Eq. (3b) is one criterion, so the per-row
+    # XLA reference scheme: Eq. (3b) is one criterion, so the per-row
     # flags must agree flip for flip (the --grid pallas campaign gate is
     # the statistical version of this at scale)
     from repro.protect.ops import QGEMM
@@ -261,13 +260,13 @@ def test_qgemm_fused_unfused_err_parity(rng):
     a = jnp.asarray(rng.integers(0, 256, size=(m, k)), jnp.uint8)
     b = jnp.asarray(rng.integers(-128, 128, size=(k, n)), jnp.int8)
     checksum = encode_weight_checksum(b)
-    unfused = ResolvedRule(scheme="unfused")
+    reference = ResolvedRule(scheme="xla")
     for s in range(10):
         b_bad = flip_bit(b, jnp.asarray(s * 41 % (k * n)),
                          jnp.asarray(s % 8))
         bp = pack_encoded_b(b_bad, checksum)
         _, err_fused = abft_qgemm_pallas(a, bp, interpret=True)
-        _, chk = QGEMM(bp, a, rule=unfused)
+        _, chk = QGEMM(bp, a, rule=reference)
         np.testing.assert_array_equal(np.asarray(err_fused).astype(bool),
                                       np.asarray(chk.err_mask))
 
@@ -466,12 +465,13 @@ def test_eb_ops_end_to_end_detection(rng):
     be = jnp.asarray(rng.uniform(-0.1, 0.1, size=128), jnp.float32)
     idx = jnp.asarray(rng.integers(0, 128, size=(4, 20)), jnp.int32)
     cs = table_rowsums(t)
-    out = ops.abft_embedding_bag(t, al, be, idx, cs, interpret=True)
+    out = ops.abft_embedding_bag(t, al, be, idx, cs, use_pallas=True)
     assert int(out.err_count) == 0
     # corrupt a *read* row's high bit => Eq. 5 must trip
     row = int(idx[0, 0])
     t_bad = t.at[row, 5].set(t[row, 5] ^ np.int8(np.uint8(0x80).view(np.int8)))
-    out_bad = ops.abft_embedding_bag(t_bad, al, be, idx, cs, interpret=True)
+    out_bad = ops.abft_embedding_bag(t_bad, al, be, idx, cs,
+                                     use_pallas=True)
     assert int(out_bad.err_count) >= 1
 
 

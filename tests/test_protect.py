@@ -29,16 +29,17 @@ def test_plan_parse_round_trip():
 
 
 def test_plan_resolution_precedence_and_paths():
-    plan = ProtectionPlan.parse(
-        "*:policy=log,qgemm:policy=recompute,qgemm/attn.*:scheme=unfused,"
-        "qgemm/attn.wq:off")
-    r = plan.resolve("qgemm", "mlp.up")
-    assert r.enabled and r.policy == "recompute" and r.scheme is None
-    r = plan.resolve("qgemm", "attn.wk")
-    assert r.enabled and r.scheme == "unfused" and r.policy == "recompute"
-    assert not plan.resolve("qgemm", "attn.wq").enabled
-    # unrelated op inherits only the wildcard
-    assert plan.resolve("embedding_bag", "tables").policy == "log"
+    for scheme in ("xla", "pallas"):
+        plan = ProtectionPlan.parse(
+            "*:policy=log,qgemm:policy=recompute,"
+            f"qgemm/attn.*:scheme={scheme},qgemm/attn.wq:off")
+        r = plan.resolve("qgemm", "mlp.up")
+        assert r.enabled and r.policy == "recompute" and r.scheme is None
+        r = plan.resolve("qgemm", "attn.wk")
+        assert r.enabled and r.scheme == scheme and r.policy == "recompute"
+        assert not plan.resolve("qgemm", "attn.wq").enabled
+        # unrelated op inherits only the wildcard
+        assert plan.resolve("embedding_bag", "tables").policy == "log"
 
 
 def test_plan_parse_rejects_garbage():
@@ -104,7 +105,8 @@ def test_qgemm_adapter_schemes_detect_flip():
     b_bad = random_bitflip(jax.random.key(7), b)
     bad_packed = jnp.concatenate([b_bad, packed[:, n:]], axis=1)
     qg = get_op("qgemm")
-    for scheme in ("packed", "unfused"):
+    assert qg.schemes == get_op("embedding_bag").schemes == ("xla", "pallas")
+    for scheme in ("xla", "pallas"):
         _, check = qg(packed, a, rule=ResolvedRule(scheme=scheme))
         assert int(check.err_count) == 0, scheme
         _, check = qg(bad_packed, a, rule=ResolvedRule(scheme=scheme))
@@ -113,6 +115,14 @@ def test_qgemm_adapter_schemes_detect_flip():
     c, _ = qg(packed, a)
     np.testing.assert_array_equal(np.asarray(qg.unprotected(packed, a)),
                                   np.asarray(c))
+
+
+@pytest.mark.parametrize("scheme", ["packed", "unfused"])
+def test_qgemm_adapter_refuses_other_schemes(scheme):
+    a, _, packed = _gemm_fixture()
+    with pytest.raises(ValueError,
+                       match=r"qgemm scheme .*\('xla', 'pallas'\)"):
+        get_op("qgemm")(packed, a, rule=ResolvedRule(scheme=scheme))
 
 
 def test_eb_adapter_rel_bound_changes_detection():

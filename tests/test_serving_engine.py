@@ -247,7 +247,7 @@ def test_dlrm_pallas_and_xla_lanes_agree_and_catch_a_flip():
             "*:policy=log,qgemm:scheme=pallas,embedding_bag:scheme=pallas",
             name="pallas")),
         TenantSpec("xla", ProtectionPlan.parse(
-            "*:policy=log,qgemm:scheme=unfused,embedding_bag:scheme=xla",
+            "*:policy=log,qgemm:scheme=xla,embedding_bag:scheme=xla",
             name="xla")),
     ]
     eng = ServingEngine(get_arch("dlrm"), tenants, dlrm_extras=ex, seed=0)
